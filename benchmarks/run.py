@@ -796,8 +796,10 @@ for name in MESHES:
         vocab=V, devices=d * m, single_us=best_s, sharded_us=best_m,
         speedup=best_s / best_m, bit_exact=exact,
         early_exit_bit_exact=exact_ee,
-        note="host-platform virtual devices share one CPU: this measures "
-             "partitioning overhead, not parallel scaling"))
+        platform=jax.default_backend(),
+        note=("host-platform virtual devices share one CPU: this measures "
+              "partitioning overhead, not parallel scaling"
+              if jax.default_backend() == "cpu" else "")))
 print("JSON:" + json.dumps(rows))
 """
 
@@ -805,9 +807,11 @@ print("JSON:" + json.dumps(rows))
 def progressive_sharded_bench(rows: list):
     """Multi-device consensus head walk -> progressive_sharded_* rows.
 
-    Runs in a subprocess with 8 virtual host-platform devices (the
-    XLA device-count flag is consumed at jax init, so this process
-    cannot grow devices itself).  Each row records the single-device
+    On the CPU it runs in a subprocess with 8 virtual host-platform
+    devices (the XLA device-count flag is consumed at jax init, so this
+    process cannot grow devices itself).  On an accelerator this process
+    already holds the devices, so the rows run in-process on the meshes
+    that fit them, or are reported skipped on a single device.  Each row records the single-device
     streaming argmax vs the shard_mapped walk on a (data, model) local
     mesh — tokens/exit levels verified bit-exact (both control flows)
     before timing.  CHECK_MODE trims shapes, meshes, and repetitions.
@@ -820,20 +824,38 @@ def progressive_sharded_bench(rows: list):
     b, k, v = (4, 256, 512) if CHECK_MODE else (8, 2048, 2048)
     reps, rounds = (1, 1) if CHECK_MODE else (10, 3)
     meshes = ["1x2"] if CHECK_MODE else ["1x2", "1x4", "2x4"]
+    on_cpu = jax.default_backend() == "cpu"
+    if not on_cpu:
+        # this process already holds the accelerator, and a chip belongs
+        # to one process: run on the real devices in-process instead of
+        # in a child that could not reach them
+        n_dev = len(jax.devices())
+        meshes = [mm for mm in meshes
+                  if np.prod([int(t) for t in mm.split("x")]) <= n_dev]
+        if not meshes:
+            emit("progressive_sharded_skipped", "n/a",
+                 f"{n_dev} {jax.default_backend()} device(s): the consensus "
+                 f"rows need >= 2 devices in this process")
+            return
     header = (f"B, K, V = {b}, {k}, {v}\n"
               f"REPS, ROUNDS = {reps}, {rounds}\n"
               f"MESHES = {meshes!r}\n")
-    out = subprocess.run(
-        [sys.executable, "-c", header + SHARDED_BENCH_BODY],
-        capture_output=True, text=True,
-        cwd=os.path.join(os.path.dirname(__file__), ".."),
-        env=virtual_device_env(8), timeout=1800)
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"sharded bench subprocess failed:\n{out.stderr[-3000:]}")
-    payload = [ln for ln in out.stdout.splitlines()
-               if ln.startswith("JSON:")][-1]
-    new_rows = json.loads(payload[len("JSON:"):])
+    if on_cpu:
+        out = subprocess.run(
+            [sys.executable, "-c", header + SHARDED_BENCH_BODY],
+            capture_output=True, text=True,
+            cwd=os.path.join(os.path.dirname(__file__), ".."),
+            env=virtual_device_env(8), timeout=1800)
+        if out.returncode != 0:
+            raise RuntimeError(
+                f"sharded bench subprocess failed:\n{out.stderr[-3000:]}")
+        payload = [ln for ln in out.stdout.splitlines()
+                   if ln.startswith("JSON:")][-1]
+        new_rows = json.loads(payload[len("JSON:"):])
+    else:
+        scope: dict = {}
+        exec(header + SHARDED_BENCH_BODY, scope)
+        new_rows = scope["rows"]
     for r in new_rows:
         emit(f"progressive_{r['name']}", r["sharded_us"],
              f"single_us={r['single_us']:.1f} speedup={r['speedup']:.2f}x "
@@ -1142,6 +1164,9 @@ def main(argv=None) -> None:
                          "the per-run JSONs are uploadable)")
     args = ap.parse_args(argv)
     CHECK_MODE = args.check
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.json_dir:
         json_dir = args.json_dir
         os.makedirs(json_dir, exist_ok=True)

@@ -43,8 +43,13 @@ print("(random untrained weights -> near-uniform logits, so argmax is "
 # head streams as the shard_mapped consensus walk whose early exit stops
 # at the fleet-wide slowest row — tokens and exit levels bit-identical
 # to the single-device engine.  A multi-device CPU needs the virtual-
-# device flag BEFORE jax initializes, so the demo runs in a subprocess.
+# device flag BEFORE jax initializes, so on the CPU the demo runs in a
+# subprocess.  On an accelerator this process already holds the chips
+# (a child could not reach them), so the demo runs here on the real
+# devices, or is skipped on a single one.
 import subprocess
+
+import jax
 
 from repro.launch.mesh import virtual_device_env
 
@@ -79,19 +84,31 @@ def run(mesh_shape):
     return eng
 
 single = run(None)
-sharded = run((2, 4))  # data=2 x model=4 over 8 virtual devices
+sharded = run(MESH)  # (data, model)
 s1, s2 = single.stats(), sharded.stats()
 assert s1 == s2, (s1, s2)
-print(f"sharded(2x4) == single-device: tokens={s2['tokens']} "
+print(f"sharded{MESH} == single-device: tokens={s2['tokens']} "
       f"mean_exit={s2['mean_exit_level']:.2f}/{s2['n_levels'] - 1} "
       f"stats identical")
 """
-print("--- sharded progressive serving (2x4 virtual-device mesh) ---")
-out = subprocess.run(
-    [sys.executable, "-c", SHARDED_DEMO], text=True, capture_output=True,
-    cwd=os.path.join(os.path.dirname(__file__), ".."),
-    env=virtual_device_env(8))
-print(out.stdout.strip())
-if out.returncode != 0:
-    print(out.stderr[-2000:])
-    sys.exit("sharded serving demo failed")
+if jax.default_backend() == "cpu":
+    print("--- sharded progressive serving (2x4 virtual-device mesh) ---")
+    out = subprocess.run(
+        [sys.executable, "-c", "MESH = (2, 4)\n" + SHARDED_DEMO], text=True,
+        capture_output=True,
+        cwd=os.path.join(os.path.dirname(__file__), ".."),
+        env=virtual_device_env(8))
+    print(out.stdout.strip())
+    if out.returncode != 0:
+        print(out.stderr[-2000:])
+        sys.exit("sharded serving demo failed")
+elif len(jax.devices()) >= 2:
+    n_dev = len(jax.devices())
+    mesh_shape = (2, 4) if n_dev >= 8 else (1, n_dev)
+    print(f"--- sharded progressive serving ({mesh_shape[0]}x{mesh_shape[1]} "
+          f"{jax.default_backend()} mesh) ---")
+    exec(f"MESH = {mesh_shape}\n" + SHARDED_DEMO, {})
+else:
+    print("--- sharded progressive serving skipped: one "
+          f"{jax.default_backend()} device, and a child process cannot "
+          "reach a chip this process holds ---")

@@ -367,7 +367,10 @@ def audit_sharding(fn: Callable, args: tuple, sharding: ShardingContract,
     HLO), input-sharding conformance — and, with ``with_cost``, prices
     the verified schedule into the sync-cost certificate."""
     name = entry or getattr(fn, "__name__", "<fn>")
-    closed = jax.make_jaxpr(fn)(*args)
+    # a jitted entry (the serving steps) is audited as it is compiled:
+    # trace its body, lower it with its own jit options
+    jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
+    closed = jax.make_jaxpr(fn.__wrapped__ if fn is jitted else fn)(*args)
     aud = _ScheduleAuditor(contract, sharding, name)
     seeds = ["int" if exactness._is_int(exactness._aval_dtype(v.aval))
              else None for v in closed.jaxpr.invars]
@@ -375,7 +378,7 @@ def audit_sharding(fn: Callable, args: tuple, sharding: ShardingContract,
     violations = list(aud.schedule_violations)
     _check_schedule(aud.records, sharding, name, violations)
 
-    compiled = jax.jit(fn).lower(*args).compile()
+    compiled = jitted.lower(*args).compile()
     text = compiled.as_text()
     hlo_v, hlo_recs = audit_partitioned_hlo(text, sharding, name)
     violations += hlo_v

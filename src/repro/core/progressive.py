@@ -586,7 +586,6 @@ def _streaming_argmax_sharded(xq, wq, xs, ws, n_bits, log2_radix, levels,
     on by the axis names.  Per-row policies shard their rows over the
     data axes like every other per-row carry.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     d = plane_count(n_bits, log2_radix)
@@ -632,11 +631,11 @@ def _streaming_argmax_sharded(xq, wq, xs, ws, n_bits, log2_radix, levels,
     if policy is not None:
         args.append(policy)
         in_specs.append(LevelPolicy(P(dp_spec), P(dp_spec), P(dp_spec)))
-    fn = shard_map(
-        walk, mesh,
+    fn = jax.shard_map(
+        walk, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(dp_spec, model_ax), P(dp_spec), P(dp_spec)),
-        check_rep=False)
+        check_vma=False)
     return fn(*args)
 
 

@@ -1,4 +1,5 @@
-"""Pallas TPU kernels (all validated in interpret mode on this CPU host):
+"""Pallas TPU kernels (compiled by default; ``interpret=True`` runs them on
+the CPU, and tests/test_tpu_compile.py compiles them for a described v5e):
 
   l2r_gemm        — MSDF digit-plane int8 GEMM (the composite IPU on the
                     MXU; the paper's primary compute hot-spot);

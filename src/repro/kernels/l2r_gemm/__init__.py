@@ -5,7 +5,7 @@ from .kernel import (l2r_gemm_pallas, l2r_gemm_pallas_stacked,
                      l2r_gemm_pallas_streaming_planes, stacked_schedule,
                      streaming_schedule)
 from .ops import (BACKENDS, BACKEND_ENV_VAR, SCHEDULES, PlaneOperands,
-                  l2r_conv2d, l2r_conv2d_progressive,
+                  l2r_conv2d, l2r_conv2d_int, l2r_conv2d_progressive,
                   l2r_conv2d_progressive_while, l2r_gemm,
                   l2r_gemm_progressive, l2r_matmul_f, pad_to,
                   resolve_backend)
@@ -17,7 +17,8 @@ __all__ = [
     "l2r_gemm_pallas_streaming_planes",
     "stacked_schedule", "streaming_schedule", "PlaneOperands",
     "l2r_gemm", "l2r_gemm_progressive", "l2r_matmul_f", "l2r_conv2d",
-    "l2r_conv2d_progressive", "l2r_conv2d_progressive_while", "pad_to",
+    "l2r_conv2d_int", "l2r_conv2d_progressive",
+    "l2r_conv2d_progressive_while", "pad_to",
     "resolve_backend", "BACKENDS", "BACKEND_ENV_VAR", "SCHEDULES",
     "l2r_gemm_ref", "l2r_gemm_ref_stacked", "int_gemm_ref",
 ]

@@ -69,11 +69,18 @@ __all__ = ["l2r_gemm_pallas", "l2r_gemm_pallas_stacked",
 
 # --------------------------------------------------------------- pair loop
 def _plane(x: jax.Array, i: int, n_planes: int, log2_radix: int) -> jax.Array:
-    """Digit plane i of an int8 tile (int32 workspace, exact for 2's comp)."""
+    """Digit plane i of an int8 tile, as int8 for the MXU.
+
+    The shifts run in an int32 workspace (exact for 2's complement); a
+    digit is at most ``log2_radix`` bits plus sign, so it narrows back
+    to int8 losslessly.  Mosaic refuses int32 operands to the MXU dot.
+    """
     xi = x.astype(jnp.int32)
     if i == n_planes - 1:
-        return xi >> (log2_radix * i)  # signed top digit
-    return (xi >> (log2_radix * i)) & ((1 << log2_radix) - 1)
+        p = xi >> (log2_radix * i)  # signed top digit
+    else:
+        p = (xi >> (log2_radix * i)) & ((1 << log2_radix) - 1)
+    return p.astype(jnp.int8)
 
 
 def _l2r_gemm_kernel(
@@ -125,7 +132,9 @@ def l2r_gemm_pallas(
 
     Shapes must be multiples of the block sizes (ops.py pads — zero
     padding is exact for matmul).  `interpret=True` runs the kernel body
-    on CPU for validation (this container has no TPU).
+    on the CPU for validation.  Operands wider than 8 bits are refused
+    at dispatch (ops.py:resolve_backend): their digit planes would not
+    be int8 tiles.
     """
     m, k = aq.shape
     k2, n = bq.shape

@@ -75,7 +75,7 @@ from .kernel import (l2r_gemm_pallas, l2r_gemm_pallas_stacked,
 from .ref import l2r_gemm_ref
 
 __all__ = ["l2r_gemm", "l2r_gemm_progressive", "l2r_attn_scores",
-           "l2r_matmul_f", "l2r_conv2d",
+           "l2r_matmul_f", "l2r_conv2d", "l2r_conv2d_int",
            "l2r_conv2d_progressive", "l2r_conv2d_progressive_while",
            "pad_to", "resolve_backend", "PlaneOperands",
            "BACKENDS", "BACKEND_ENV_VAR", "SCHEDULES"]
@@ -86,7 +86,8 @@ BACKENDS = ("jnp", "pallas-interpret", "pallas-tpu")
 BACKEND_ENV_VAR = "REPRO_L2R_BACKEND"
 
 
-def resolve_backend(backend: str | None = None) -> str:
+def resolve_backend(backend: str | None = None,
+                    n_bits: int | None = None) -> str:
     """Dispatch rule: explicit arg > $REPRO_L2R_BACKEND > platform default.
 
     The platform default is ``pallas-tpu`` when jax runs on TPU and the
@@ -100,7 +101,22 @@ def resolve_backend(backend: str | None = None) -> str:
     too, naming the env var and the valid backends — resolve time is the
     ONE place a bad env value can fail early instead of surfacing as an
     arbitrary downstream error.
+
+    ``n_bits`` is the digit config of the call.  The compiled kernels
+    feed int8 digit-plane tiles to the MXU, so a config wider than 8
+    bits (int16 plane stacks, which Mosaic refuses) is rejected here on
+    ``pallas-tpu``, naming the config.
     """
+    chosen = _resolve(backend)
+    if chosen == "pallas-tpu" and n_bits is not None and n_bits > 8:
+        raise ValueError(
+            f"the pallas-tpu L2R kernels take int8 digit planes, but this "
+            f"call's digit config has n_bits={n_bits} (int16 planes); use "
+            f"an n_bits <= 8 QuantConfig, or backend='jnp'")
+    return chosen
+
+
+def _resolve(backend: str | None) -> str:
     source = "backend argument"
     chosen = backend
     if not chosen:
@@ -316,7 +332,7 @@ def l2r_gemm(
             f"early_exit is a streaming-schedule control flow; "
             f"schedule={schedule!r} has no level loop to stop short "
             f"(it would be silently dropped)")
-    resolved = resolve_backend(backend)
+    resolved = resolve_backend(backend, n_bits)
     if early_exit and resolved != "jnp":
         raise ValueError(
             f"early_exit=True is the jnp while-loop emitter; the "
@@ -391,7 +407,8 @@ def l2r_gemm_progressive(
     _check_plane_operand(aq, "lhs", n_bits, log2_radix, other=bq)
     _check_plane_operand(bq, "rhs", n_bits, log2_radix, other=aq)
     return _l2r_gemm_progressive_backend(aq, bq, n_bits, log2_radix, levels,
-                                         bm, bk, bn, resolve_backend(backend))
+                                         bm, bk, bn,
+                                         resolve_backend(backend, n_bits))
 
 
 def _attn_pallas_scores(q_po: PlaneOperands, k_po: PlaneOperands,
@@ -504,7 +521,7 @@ def l2r_attn_scores(
             f"early_exit is a streaming-schedule control flow; "
             f"schedule={schedule!r} has no level loop to stop short "
             f"(it would be silently dropped)")
-    resolved = resolve_backend(backend)
+    resolved = resolve_backend(backend, n_bits)
     if early_exit and resolved != "jnp":
         raise ValueError(
             f"early_exit=True is the jnp while-loop emitter; the "
@@ -710,20 +727,35 @@ def l2r_conv2d(
     """
     if w_q is None:
         w_q = quantize_weights(w, cfg)  # (kh,kw,cin,cout), scale (1,1,1,cout)
-    from repro.analysis.overflow import check_or_raise as _certify
-    kh, kw, cin, _ = w_q.q.shape
-    _certify(cfg.n_bits, cfg.log2_radix, int(cin), levels=levels,
-             taps=int(kh * kw), where="l2r_conv2d")
     xq, xs = quantize(x, cfg, axis=0)  # per-image scales (B,1,1,1)
-    out = _l2r_conv2d_int(xq, _conv_w_in(w_q, cfg), cfg.n_bits,
-                          cfg.log2_radix, levels,
-                          resolve_backend(backend), _pair(stride),
-                          _pair(dilation))
+    out = l2r_conv2d_int(xq, w_q, cfg, levels, backend, stride, dilation)
     out = out.astype(jnp.float32) * xs * w_q.scale.reshape(1, 1, 1, -1)
     out = out.astype(x.dtype)
     if b is not None:
         out = out + b.astype(out.dtype)
     return out
+
+
+def l2r_conv2d_int(
+    xq: jax.Array,
+    w_q: QuantizedWeights,
+    cfg: QuantConfig = QuantConfig(),
+    levels: int | None = None,
+    backend: str | None = None,
+    stride: int | tuple[int, int] = 1,
+    dilation: int | tuple[int, int] = 1,
+) -> jax.Array:
+    """Integer core of :func:`l2r_conv2d`: quantized activations ``xq``
+    (B, H, W, cin) against the weight cache ``w_q`` -> int32 (B, OH, OW,
+    cout), before dequantization.  Bit-identical across backends."""
+    from repro.analysis.overflow import check_or_raise as _certify
+    kh, kw, cin, _ = w_q.q.shape
+    _certify(cfg.n_bits, cfg.log2_radix, int(cin), levels=levels,
+             taps=int(kh * kw), where="l2r_conv2d")
+    return _l2r_conv2d_int(xq, _conv_w_in(w_q, cfg), cfg.n_bits,
+                           cfg.log2_radix, levels,
+                           resolve_backend(backend, cfg.n_bits),
+                           _pair(stride), _pair(dilation))
 
 
 def _pair(v: int | tuple[int, int]) -> tuple[int, int]:
@@ -951,7 +983,8 @@ def l2r_conv2d_progressive(
     kh, kw, cin, _ = w_q.q.shape
     stack = _l2r_conv2d_progressive_int(
         xq, _conv_w_in(w_q, cfg), cfg.n_bits, cfg.log2_radix, levels,
-        resolve_backend(backend), _pair(stride), _pair(dilation))
+        resolve_backend(backend, cfg.n_bits), _pair(stride),
+        _pair(dilation))
     bounds = level_bounds(cfg.planes, cfg.log2_radix, kh * kw * cin, levels)
     result = ProgressiveResult(partial=stack, tail_bound=bounds.f32,
                                bound_i32=bounds.i32,

@@ -60,7 +60,7 @@ def _kernel(a_ref, b_ref, out_ref, *, n_bits: int, k: int):
 
 @functools.partial(jax.jit, static_argnames=("n_bits", "bm", "interpret"))
 def cipu_array_pallas(a: jax.Array, b: jax.Array, n_bits: int = 8,
-                      bm: int = 256, interpret: bool = True) -> jax.Array:
+                      bm: int = 256, interpret: bool = False) -> jax.Array:
     """a, b: (M, k) unsigned operands -> (M,) exact SOPs, simulated at
     the register level.  M must divide into bm-sized PE batches (padded
     here)."""

@@ -104,7 +104,6 @@ def lower_cell(arch: str, shape: str, multi_pod: bool,
         n_tokens = sp.global_batch * sp.seq_len
     elif sp.kind == "prefill":
         params_abs = abstract(desc, param_dtype=jnp.bfloat16)
-        step = make_prefill_step(cfg, max_len=sp.seq_len)
         pspecs = named(mesh, param_specs(desc, mesh))
         bspecs = named(mesh, _batch_shardings(mesh, specs))
         sspecs = named(mesh, state_specs(cfg, mesh, sp.global_batch, sp.seq_len,
@@ -112,14 +111,14 @@ def lower_cell(arch: str, shape: str, multi_pod: bool,
         lspec = named(mesh, safe_spec(
             (sp.global_batch, 1, cfg.vocab),
             P(batch_spec(mesh, sp.global_batch)[0], None, "model"), mesh))
-        fn = jax.jit(step, in_shardings=(pspecs, bspecs),
-                     out_shardings=(sspecs, lspec))
+        fn = make_prefill_step(cfg, max_len=sp.seq_len,
+                               in_shardings=(pspecs, bspecs),
+                               out_shardings=(sspecs, lspec))
         lowered = fn.lower(params_abs, specs)
         n_tokens = sp.global_batch * sp.seq_len
     else:  # decode
         params_abs = abstract(desc, param_dtype=jnp.bfloat16)
         state_abs = abstract_state(cfg, sp.global_batch, sp.seq_len)
-        step = make_decode_step(cfg)
         pspecs = named(mesh, param_specs(desc, mesh))
         sspecs = named(mesh, state_specs(cfg, mesh, sp.global_batch, sp.seq_len,
                                          kv_shard))
@@ -132,9 +131,10 @@ def lower_cell(arch: str, shape: str, multi_pod: bool,
         if "rope_positions" in specs:
             in_sh = in_sh + (named(mesh, P(None, bspec, None)),)
             args = args + (specs["rope_positions"],)
-        fn = jax.jit(step, in_shardings=in_sh,
-                     out_shardings=(sspecs, named(mesh, P(bspec, None)), lspec),
-                     donate_argnums=(1,))
+        fn = make_decode_step(
+            cfg, in_shardings=in_sh,
+            out_shardings=(sspecs, named(mesh, P(bspec, None)), lspec),
+            donate_argnums=(1,))
         lowered = fn.lower(*args)
         n_tokens = sp.global_batch  # one new token per sequence
 

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh", "install_local_mesh",
            "VIRTUAL_DEVICES_FLAG", "virtual_device_env"]
@@ -18,12 +19,17 @@ def make_production_mesh(*, multi_pod: bool = False):
     leading pod axis: (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
-    """Small mesh over whatever devices exist (tests / CPU smoke)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    """Small mesh over whatever devices exist (tests / CPU smoke).
+
+    Axes are ``Auto``: GSPMD propagates shardings from the
+    ``with_sharding_constraint`` hints (sharding/ctx.py), which reject
+    ``Explicit`` axes — the ``jax.make_mesh`` default."""
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def install_local_mesh(data: int = 1, model: int = 1):

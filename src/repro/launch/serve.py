@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke
 from repro.core.quant import QuantConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.common import materialize, quantize_params
 from repro.models.transformer import lm_build
 from repro.serve.engine import (make_decode_step, make_prefill_step,
@@ -48,6 +49,7 @@ def main(argv=None):
                          "(bucketed AOT prefill, donated decode, async "
                          "emit) instead of the static-batch loop")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     assert cfg.family not in ("encdec",), "use examples for enc-dec serving"
@@ -91,8 +93,8 @@ def main(argv=None):
         for i, row in enumerate(seqs):
             print(f"seq{i}: {row.tolist()}")
         return seqs
-    prefill = jax.jit(make_prefill_step(cfg, max_len, cache_dtype=jnp.float32))
-    decode = jax.jit(make_decode_step(cfg))
+    prefill = make_prefill_step(cfg, max_len, cache_dtype=jnp.float32)
+    decode = make_decode_step(cfg)
 
     t0 = time.time()
     state, logits = prefill(params, {"tokens": prompt})

@@ -25,6 +25,7 @@ import numpy as np
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_config, get_smoke
 from repro.data.pipeline import DataConfig, ShardedPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.common import materialize
 from repro.models.encdec import encdec_build
 from repro.models.transformer import lm_build
@@ -49,6 +50,7 @@ def main(argv=None):
     ap.add_argument("--microbatch", type=int, default=1)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     assert cfg.family != "encdec" or not args.smoke or True

@@ -94,7 +94,8 @@ def _mha(cfg, p, xq, xkv, *, causal, mode="train", cache=None, positions=None):
     if mode == "decode":
         cache = update_kv_cache(cache, k, v, positions)
         out = decode_attention(q, cache.k, cache.v, cache.positions,
-                               positions[:, 0], scale=cfg.attn_scale)
+                               positions[:, 0],
+                               scale=cfg.attn_scale).astype(q.dtype)
     else:
         if mode == "prefill":
             cache = update_kv_cache(cache, k, v, positions)

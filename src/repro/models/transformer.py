@@ -112,7 +112,7 @@ def attn_apply(
             l2r=cfg.attn_l2r, levels=cfg.attn_levels,
             early_exit=cfg.attn_early_exit, exit_tol=cfg.attn_exit_tol,
             k_planes=cache.k_planes, k_scale=cache.k_scale,
-        )
+        ).astype(q.dtype)  # a wider cache must not widen the residual
     else:
         if mode == "prefill":
             # a plane-stacked cache fills incrementally here too: decode

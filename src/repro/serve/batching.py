@@ -302,14 +302,13 @@ class ContinuousBatcher:
         # a replicated computation onto model axes and float-reassociate
         # backbone contractions; see ctx.hints_disabled)
         hints = state_sharding != "replicated"
-        self._decode = jax.jit(make_decode_step(cfg, progressive=progressive,
-                                                early_exit=early_exit,
-                                                backbone_hints=hints,
-                                                mesh=self.mesh),
-                               donate_argnums=(1,) if donate_state else ())
-        self._prefill1 = jax.jit(make_prefill_step(
+        self._decode = make_decode_step(
+            cfg, progressive=progressive, early_exit=early_exit,
+            backbone_hints=hints, mesh=self.mesh,
+            donate_argnums=(1,) if donate_state else ())
+        self._prefill1 = make_prefill_step(
             cfg, max_len, cache_dtype, progressive=progressive,
-            early_exit=early_exit, backbone_hints=hints, mesh=self.mesh))
+            early_exit=early_exit, backbone_hints=hints, mesh=self.mesh)
         if bucketed is None:
             local = any(k == "local" for k, _ in cfg.layer_kinds())
             bucketed = supports_bucketed_prefill(cfg) and \
@@ -317,9 +316,9 @@ class ContinuousBatcher:
         self.bucketed = bucketed
         if bucketed:
             self._buckets = prefill_buckets(max_len)
-            self._bucket_prefill = jax.jit(make_bucket_prefill_step(
+            self._bucket_prefill = make_bucket_prefill_step(
                 cfg, max_len, cache_dtype, progressive=progressive,
-                early_exit=early_exit, backbone_hints=hints, mesh=self.mesh))
+                early_exit=early_exit, backbone_hints=hints, mesh=self.mesh)
         self.steps = 0
         # saved-levels accounting (progressive mode): histograms over the
         # MSDF exit level of every decoded token across all requests AND

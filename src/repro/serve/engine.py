@@ -31,7 +31,23 @@ __all__ = ["prepare_params", "make_prefill_step", "make_decode_step",
            "make_bucket_prefill_step", "prefill_buckets", "bucket_for",
            "supports_bucketed_prefill",
            "progressive_logits_from_hidden", "state_specs", "abstract_state",
-           "greedy_generate"]
+           "greedy_generate", "SERVE_COMPILER_OPTIONS"]
+
+
+# XLA may keep a bf16 intermediate in f32 inside a fusion ("excess
+# precision"), and which values it keeps depends on the fusions it picks
+# for a shape.  On the TPU that made a request's prefill logits and KV
+# cache differ in the last bits between a one-row and a packed four-row
+# bucket, so its tokens depended on what it was batched with.  Serving
+# executables round every value where the program says: on the TPU a
+# bf16 row's result is then the same in any batch, as the gateway/
+# batcher parity requires (float32 rows still are not; see PERF.md).
+# The step factories below return their step jitted with these options.
+SERVE_COMPILER_OPTIONS = {"xla_allow_excess_precision": False}
+
+
+def _serve_jit(fn: Callable, jit_kwargs: dict) -> Callable:
+    return jax.jit(fn, compiler_options=SERVE_COMPILER_OPTIONS, **jit_kwargs)
 
 
 # ------------------------------------------------------- weight preparation
@@ -201,6 +217,35 @@ def abstract_state(cfg: ModelConfig, batch: int, max_len: int,
 
 
 # ------------------------------------------------------------ step factories
+def _replicated_backbone(fn: Callable, mesh: Mesh | None,
+                         backbone_hints: bool) -> Callable:
+    """``fn`` (a backbone forward) for the replicated-backbone mesh
+    setting: one program per device on replicated operands.
+
+    With ``backbone_hints=False`` on a multi-device mesh (``mesh``, or
+    the installed context mesh), every device computes the whole
+    backbone, as GSPMD would for replicated operands.  Inside
+    ``shard_map`` no partitioner touches it: the compiled Pallas kernels
+    lower (Mosaic kernels cannot be partitioned automatically), and each
+    device runs the unmeshed trace, bit for bit.  Otherwise ``fn`` is
+    returned as is.  Call with :func:`_backbone_params`, so the
+    vocab-sharded head cache is not gathered into the backbone."""
+    from repro.sharding import ctx
+
+    mesh = mesh if mesh is not None else ctx.get_mesh()
+    if backbone_hints or mesh is None or mesh.size == 1:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
+
+
+def _backbone_params(params):
+    """The parameters the backbone reads: all but the LM-head cache."""
+    if isinstance(params, dict) and "head_q" in params:
+        return {k: v for k, v in params.items() if k != "head_q"}
+    return params
+
+
 def _check_step_flags(progressive: bool, early_exit: bool,
                       policy: LevelPolicy | None = None) -> None:
     """Reject contradictory step-factory flag combinations.
@@ -229,8 +274,9 @@ def make_prefill_step(cfg: ModelConfig, max_len: int,
                       early_exit: bool = False,
                       backbone_hints: bool = True,
                       mesh: Mesh | None = None,
-                      policy: LevelPolicy | None = None) -> Callable:
-    """(params, batch) -> (state, last_token_logits).
+                      policy: LevelPolicy | None = None,
+                      **jit_kwargs) -> Callable:
+    """(params, batch) -> (state, last_token_logits), jitted.
 
     ``progressive=True`` (LM families, requires ``cfg.l2r``) is
     batch-level progressive prefill: the backbone runs exactly over the
@@ -259,6 +305,12 @@ def make_prefill_step(cfg: ModelConfig, max_len: int,
     step argument) routes the head stream through per-row
     :class:`~repro.core.policy.LevelPolicy` precision classes — one row
     per batch entry; ``early_exit`` stays as the batch-global shim.
+
+    Every step factory returns its step as ``jax.jit(step,
+    compiler_options=SERVE_COMPILER_OPTIONS, **jit_kwargs)`` (donation,
+    shardings), so every serving program is compiled the same way.
+    Call, ``.lower()`` or audit the returned step; JAX refuses it inside
+    another ``jax.jit``.
     """
     _check_step_flags(progressive, early_exit, policy)
     default_policy = policy
@@ -289,10 +341,12 @@ def make_prefill_step(cfg: ModelConfig, max_len: int,
             embeds = batch.get("embeds")
             bsz = (tokens if tokens is not None else embeds).shape[0]
             state = init_lm_state(cfg, bsz, max_len, cache_dtype)
-            hidden, state, _ = lm_forward(
-                cfg, params, tokens=tokens, embeds=embeds,
-                rope_positions=batch.get("rope_positions"),
-                mode="prefill", state=state)
+            fwd = _replicated_backbone(
+                lambda p, s, t, e, rp: lm_forward(
+                    cfg, p, tokens=t, embeds=e, rope_positions=rp,
+                    mode="prefill", state=s), mesh, backbone_hints)
+            hidden, state, _ = fwd(_backbone_params(params), state, tokens,
+                                   embeds, batch.get("rope_positions"))
         if progressive:
             logits, tok, lv = progressive_logits_from_hidden(
                 cfg, params, hidden[:, -1:], early_exit=early_exit,
@@ -302,7 +356,7 @@ def make_prefill_step(cfg: ModelConfig, max_len: int,
         logits = logits_from_hidden(cfg, params, hidden[:, -1:])
         return state, logits
 
-    return prefill
+    return _serve_jit(prefill, jit_kwargs)
 
 
 # ------------------------------------------------------- bucketed prefill
@@ -376,7 +430,8 @@ def make_bucket_prefill_step(cfg: ModelConfig, max_len: int,
                              early_exit: bool = False,
                              backbone_hints: bool = True,
                              mesh: Mesh | None = None,
-                             policy: LevelPolicy | None = None) -> Callable:
+                             policy: LevelPolicy | None = None,
+                             **jit_kwargs) -> Callable:
     """(params, tokens (B, Lb), true_len (B,)) -> make_prefill_step returns.
 
     The bucketed form of :func:`make_prefill_step`: ``tokens`` is a
@@ -425,8 +480,10 @@ def make_bucket_prefill_step(cfg: ModelConfig, max_len: int,
                 f"{cfg.window}: the ring cache would wrap over real "
                 f"prompt entries")
         state = init_lm_state(cfg, bsz, max_len, cache_dtype)
-        hidden, state, _ = lm_forward(cfg, params, tokens=tokens,
-                                      mode="prefill", state=state)
+        fwd = _replicated_backbone(
+            lambda p, s, t: lm_forward(cfg, p, tokens=t, mode="prefill",
+                                       state=s), mesh, backbone_hints)
+        hidden, state, _ = fwd(_backbone_params(params), state, tokens)
         idx = (true_len.astype(jnp.int32) - 1)[:, None, None]
         h_last = jnp.take_along_axis(hidden, idx, axis=1)  # (B, 1, d)
         state = _mask_bucket_state(state, true_len)
@@ -437,7 +494,7 @@ def make_bucket_prefill_step(cfg: ModelConfig, max_len: int,
             return state, logits, tok.astype(jnp.int32), lv
         return state, logits_from_hidden(cfg, params, h_last)
 
-    return prefill
+    return _serve_jit(prefill, jit_kwargs)
 
 
 def progressive_logits_from_hidden(cfg: ModelConfig, params, hidden,
@@ -502,8 +559,10 @@ def make_decode_step(cfg: ModelConfig, progressive: bool = False,
                      early_exit: bool = False,
                      backbone_hints: bool = True,
                      mesh: Mesh | None = None,
-                     policy: LevelPolicy | None = None) -> Callable:
-    """(params, state, tokens (B,1)) -> (state, next_tokens (B,1), logits).
+                     policy: LevelPolicy | None = None,
+                     **jit_kwargs) -> Callable:
+    """(params, state, tokens (B,1)) -> (state, next_tokens (B,1), logits),
+    jitted.
 
     ``progressive=True`` (LM families, requires ``cfg.l2r``) streams the
     final head matmul most-significant-level first and commits each
@@ -552,9 +611,12 @@ def make_decode_step(cfg: ModelConfig, progressive: bool = False,
             hidden, state, _ = encdec_forward(
                 cfg, params, tokens=tokens, mode="decode", state=state)
         else:
-            hidden, state, _ = lm_forward(
-                cfg, params, tokens=tokens, rope_positions=rope_positions,
-                mode="decode", state=state)
+            fwd = _replicated_backbone(
+                lambda p, s, t, rp: lm_forward(
+                    cfg, p, tokens=t, rope_positions=rp, mode="decode",
+                    state=s), mesh, backbone_hints)
+            hidden, state, _ = fwd(_backbone_params(params), state, tokens,
+                                   rope_positions)
         if progressive:
             logits, tok, lv = progressive_logits_from_hidden(
                 cfg, params, hidden, early_exit=early_exit, mesh=mesh,
@@ -564,7 +626,7 @@ def make_decode_step(cfg: ModelConfig, progressive: bool = False,
         next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return state, next_tok, logits
 
-    return decode
+    return _serve_jit(decode, jit_kwargs)
 
 
 def greedy_generate(cfg: ModelConfig, params, prompt: jax.Array, steps: int,
@@ -572,8 +634,8 @@ def greedy_generate(cfg: ModelConfig, params, prompt: jax.Array, steps: int,
     """Batched greedy decoding loop (host-driven; example/serving path)."""
     b, s = prompt.shape
     max_len = max_len or (s + steps)
-    prefill = jax.jit(make_prefill_step(cfg, max_len, cache_dtype))
-    decode = jax.jit(make_decode_step(cfg))
+    prefill = make_prefill_step(cfg, max_len, cache_dtype)
+    decode = make_decode_step(cfg)
     state, logits = prefill(params, {"tokens": prompt})
     tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     out = [tok]

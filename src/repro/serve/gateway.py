@@ -202,16 +202,14 @@ class ServingGateway:
         # replicated backbone -> interior sharding hints scoped off (see
         # ContinuousBatcher: they would float-reassociate contractions)
         hints = False if self.mesh is not None else True
-        self._prefill_fn = make_bucket_prefill_step(
-            cfg, max_len, cache_dtype, progressive=progressive,
-            early_exit=early_exit, backbone_hints=hints, mesh=self.mesh)
-        self._decode_fn = make_decode_step(
-            cfg, progressive=progressive, early_exit=early_exit,
-            backbone_hints=hints, mesh=self.mesh)
         # fallback jitted entry points (shape-keyed cache: still one
         # trace per bucket); AOT warmup swaps in Compiled executables
-        self._prefill_jit = jax.jit(self._prefill_fn)
-        self._decode_jit = jax.jit(self._decode_fn, donate_argnums=(1,))
+        self._prefill_jit = make_bucket_prefill_step(
+            cfg, max_len, cache_dtype, progressive=progressive,
+            early_exit=early_exit, backbone_hints=hints, mesh=self.mesh)
+        self._decode_jit = make_decode_step(
+            cfg, progressive=progressive, early_exit=early_exit,
+            backbone_hints=hints, mesh=self.mesh, donate_argnums=(1,))
         self._prefill_exe: dict[int, object] = {}
         self._decode_exe = None
         if aot_warmup:
@@ -269,16 +267,13 @@ class ServingGateway:
                     jax.ShapeDtypeStruct((g,), jnp.int32)]
             if self.progressive:
                 args.append(pol_sds(g))
-            self._prefill_exe[lb] = (
-                jax.jit(self._prefill_fn).lower(*args).compile())
+            self._prefill_exe[lb] = self._prefill_jit.lower(*args).compile()
         if self._decode_exe is None:
             args = [self.params, self.state,
                     jax.ShapeDtypeStruct((self.n_slots, 1), jnp.int32)]
             if self.progressive:
                 args.extend([None, pol_sds(self.n_slots)])
-            self._decode_exe = (
-                jax.jit(self._decode_fn, donate_argnums=(1,))
-                .lower(*args).compile())
+            self._decode_exe = self._decode_jit.lower(*args).compile()
 
     # ------------------------------------------------------------- api
     def submit(self, req: Request):

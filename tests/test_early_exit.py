@@ -246,10 +246,9 @@ def test_progressive_prefill_streams_last_token_only(l2r_lm):
     cfg, params = l2r_lm
     rng = np.random.default_rng(5)
     prompt = jnp.asarray(rng.integers(0, cfg.vocab, (2, 8)), jnp.int32)
-    ref_prefill = jax.jit(make_prefill_step(cfg, 32, jnp.float32))
+    ref_prefill = make_prefill_step(cfg, 32, jnp.float32)
     st_r, logits_r = ref_prefill(params, {"tokens": prompt})
-    prog_prefill = jax.jit(make_prefill_step(cfg, 32, jnp.float32,
-                                             progressive=True))
+    prog_prefill = make_prefill_step(cfg, 32, jnp.float32, progressive=True)
     st_p, logits_p, tok, lv = prog_prefill(params, {"tokens": prompt})
     np.testing.assert_array_equal(
         np.asarray(tok), np.asarray(logits_r).argmax(-1))
@@ -268,11 +267,11 @@ def test_decode_step_early_exit_tokens_identical(l2r_lm):
     cfg, params = l2r_lm
     rng = np.random.default_rng(13)
     prompt = jnp.asarray(rng.integers(0, cfg.vocab, (2, 8)), jnp.int32)
-    prefill = jax.jit(make_prefill_step(cfg, 32, jnp.float32))
+    prefill = make_prefill_step(cfg, 32, jnp.float32)
     state, logits = prefill(params, {"tokens": prompt})
     tok = jnp.argmax(logits, -1).astype(jnp.int32)
-    dec_s = jax.jit(make_decode_step(cfg, progressive=True))
-    dec_e = jax.jit(make_decode_step(cfg, progressive=True, early_exit=True))
+    dec_s = make_decode_step(cfg, progressive=True)
+    dec_e = make_decode_step(cfg, progressive=True, early_exit=True)
     st_s, st_e = state, state
     for _ in range(4):
         st_s, tok_s, _, lv_s = dec_s(params, st_s, tok)

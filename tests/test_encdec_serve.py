@@ -60,3 +60,24 @@ def test_cross_attention_cache_reused(model):
     h_dec, _, _ = encdec_forward(cfg, params, tokens=toks[:, 11:12],
                                  mode="decode", state=st)
     assert np.isfinite(np.asarray(h_dec)).all()
+
+
+def test_decode_bf16_compute_with_f32_cache(model):
+    """At the published bf16 compute dtype, decoding from the f32 serving
+    cache keeps the residual stream in bf16 (the layer scan's carry
+    dtype) and follows the train forward."""
+    import dataclasses
+
+    cfg, params, frames, toks = model
+    cfg = dataclasses.replace(cfg, compute_dtype="bfloat16")
+    h, _, _ = encdec_forward(cfg, params, tokens=toks, frames=frames,
+                             mode="train")
+    st = init_encdec_state(cfg, 2, 16, jnp.float32)
+    _, st, _ = encdec_forward(cfg, params, tokens=toks[:, :11], frames=frames,
+                              mode="prefill", state=st)
+    h_dec, _, _ = encdec_forward(cfg, params, tokens=toks[:, 11:12],
+                                 mode="decode", state=st)
+    assert h_dec.dtype == jnp.bfloat16
+    ref = np.asarray(h[:, 11:12], np.float32)
+    err = np.abs(np.asarray(h_dec, np.float32) - ref).max()
+    assert err <= 0.05 * np.abs(ref).max(), err

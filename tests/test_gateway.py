@@ -25,9 +25,12 @@ def model():
     return cfg, params
 
 
-@pytest.fixture(scope="module")
-def prog_model():
-    cfg = dataclasses.replace(get_smoke("smollm-135m"), l2r=QuantConfig())
+@pytest.fixture(scope="module", params=["float32", "bfloat16"])
+def prog_model(request):
+    """The L2R smoke model at float32 compute and at bf16, the published
+    config's compute dtype (the gateway keeps its f32 cache)."""
+    cfg = dataclasses.replace(get_smoke("smollm-135m"), l2r=QuantConfig(),
+                              compute_dtype=request.param)
     params = prepare_params(cfg, materialize(lm_build(cfg),
                                              jax.random.PRNGKey(0)))
     return cfg, params
@@ -132,6 +135,15 @@ def test_gateway_progressive_exit_level_parity(prog_model):
     assert st["tokens"] == sum(len(r.output) for r in served)
     assert sum(st["exit_level_hist"]) == sum(
         len(r.exit_levels) for r in served)
+    if cfg.compute_dtype == "bfloat16":
+        # the AOT decode executable is compiled with SERVE_COMPILER_OPTIONS:
+        # it keeps bf16 roundings that a plain jit of the same step drops
+        args, _ = gw._decode_exe.args_info
+        sds = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                           args)
+        plain = jax.jit(gw._decode_jit.__wrapped__).lower(*sds).compile()
+        n_converts = lambda exe: exe.as_text().count(" convert(")
+        assert n_converts(gw._decode_exe) > n_converts(plain)
 
 
 # ------------------------------------------------------------ slot churn

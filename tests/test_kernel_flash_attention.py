@@ -29,7 +29,7 @@ def test_flash_vs_oracle(case):
     out = flash_attention_pallas(
         jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
         causal=case["causal"], window=case["window"],
-        bq=case["bq"], bkv=case["bkv"])
+        bq=case["bq"], bkv=case["bkv"], interpret=True)
     ref = attention_ref(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
                         causal=case["causal"], window=case["window"])
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
@@ -40,7 +40,7 @@ def test_flash_bf16_inputs():
     q = jnp.asarray(rng.standard_normal((1, 128, 4, 64)), jnp.bfloat16)
     k = jnp.asarray(rng.standard_normal((1, 128, 2, 64)), jnp.bfloat16)
     v = jnp.asarray(rng.standard_normal((1, 128, 2, 64)), jnp.bfloat16)
-    out = flash_attention_pallas(q, k, v, bq=64, bkv=64)
+    out = flash_attention_pallas(q, k, v, bq=64, bkv=64, interpret=True)
     ref = attention_ref(q, k, v)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=3e-2)
@@ -54,6 +54,7 @@ def test_flash_matches_model_attention():
     q = jnp.asarray(rng.standard_normal((2, 256, 4, 64)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((2, 256, 2, 64)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((2, 256, 2, 64)), jnp.float32)
-    out = flash_attention_pallas(q, k, v, causal=True, bq=128, bkv=128)
+    out = flash_attention_pallas(q, k, v, causal=True, bq=128, bkv=128,
+                                 interpret=True)
     ref = chunked_attention(q, k, v, causal=True, q_chunk=128, kv_chunk=128)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)

@@ -221,6 +221,23 @@ def test_resolve_backend_pallas_tpu_off_platform(monkeypatch):
                  backend="pallas-tpu")
 
 
+def test_resolve_backend_rejects_wide_digits_on_tpu(monkeypatch):
+    """The compiled kernels take int8 digit planes: a >8-bit config
+    (int16 planes, refused by Mosaic) fails on pallas-tpu at resolve
+    time, naming the config; <=8-bit configs and other backends pass."""
+    import jax
+
+    from repro.kernels.l2r_gemm import resolve_backend
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_backend(None, 8) == "pallas-tpu"
+    with pytest.raises(ValueError, match="n_bits=12"):
+        resolve_backend(None, 12)
+    with pytest.raises(ValueError, match="n_bits=16"):
+        resolve_backend("pallas-tpu", 16)
+    assert resolve_backend("jnp", 12) == "jnp"
+
+
 def test_pad_to_rank_mismatch_raises():
     """pad_to used to zip-truncate when len(mults) != ndim, silently
     leaving dims unpadded — now a ValueError both ways."""

@@ -14,7 +14,7 @@ def test_pe_array_exact(m, k, n_bits):
     hi = 1 << n_bits
     a = jnp.asarray(rng.integers(0, hi, (m, k)), jnp.int32)
     b = jnp.asarray(rng.integers(0, hi, (m, k)), jnp.int32)
-    out = cipu_array_pallas(a, b, n_bits, bm=64)
+    out = cipu_array_pallas(a, b, n_bits, bm=64, interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(int_sop_ref(a, b)))
     np.testing.assert_array_equal(np.asarray(out),
                                   np.asarray(cipu_array_ref(a, b, n_bits)))

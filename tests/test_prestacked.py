@@ -357,9 +357,8 @@ def test_engine_prestack_cache_bit_identical():
     pre = prepare_params(cfg, params, prestack=True)
     rng = np.random.default_rng(41)
     prompt = jnp.asarray(rng.integers(0, cfg.vocab, (2, 6)), jnp.int32)
-    prefill = jax.jit(make_prefill_step(cfg, 32, jnp.float32,
-                                        progressive=True))
-    decode = jax.jit(make_decode_step(cfg, progressive=True))
+    prefill = make_prefill_step(cfg, 32, jnp.float32, progressive=True)
+    decode = make_decode_step(cfg, progressive=True)
     s1, lg1, t1, lv1 = prefill(plain, {"tokens": prompt})
     s2, lg2, t2, lv2 = prefill(pre, {"tokens": prompt})
     np.testing.assert_array_equal(np.asarray(t1), np.asarray(t2))

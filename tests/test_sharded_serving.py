@@ -315,15 +315,13 @@ SHARDED_SERVING = textwrap.dedent("""
         # replicated backbone on a mesh -> interior hints scoped off
         # (the bit-parity serving contract; the head walk still shards)
         hints = mesh is None
-        prefill = jax.jit(make_prefill_step(cfg, 24, jnp.float32,
-                                            progressive=True,
-                                            early_exit=True,
-                                            backbone_hints=hints))
+        prefill = make_prefill_step(cfg, 24, jnp.float32,
+                                    progressive=True, early_exit=True,
+                                    backbone_hints=hints)
         state, logits, tok, lv = prefill(params, {"tokens": prompt})
         toks, lvs = [np.asarray(tok)], [np.asarray(lv)]
-        dec = jax.jit(make_decode_step(cfg, progressive=True,
-                                       early_exit=True,
-                                       backbone_hints=hints))
+        dec = make_decode_step(cfg, progressive=True, early_exit=True,
+                               backbone_hints=hints)
         cur = tok.astype(jnp.int32)
         for _ in range(3):
             state, cur, _, lv = dec(params, state, cur)

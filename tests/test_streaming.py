@@ -241,8 +241,8 @@ def test_progressive_decode_tokens_identical_to_greedy(l2r_lm):
     prompt = jnp.asarray(rng.integers(0, cfg.vocab, (2, 8)), jnp.int32)
     ref = np.asarray(greedy_generate(cfg, params, prompt, steps=6,
                                      max_len=32))
-    prefill = jax.jit(make_prefill_step(cfg, 32, jnp.float32))
-    decode = jax.jit(make_decode_step(cfg, progressive=True))
+    prefill = make_prefill_step(cfg, 32, jnp.float32)
+    decode = make_decode_step(cfg, progressive=True)
     state, logits = prefill(params, {"tokens": prompt})
     tok = jnp.argmax(logits, -1).astype(jnp.int32)
     out, levels = [np.asarray(tok)], []
@@ -265,13 +265,12 @@ def test_progressive_decode_respects_l2r_levels(l2r_lm):
     params = l2r_lm[1]
     rng = np.random.default_rng(7)
     prompt = jnp.asarray(rng.integers(0, cfg5.vocab, (2, 8)), jnp.int32)
-    prefill = jax.jit(make_prefill_step(cfg5, 16, jnp.float32))
+    prefill = make_prefill_step(cfg5, 16, jnp.float32)
     state, logits = prefill(params, {"tokens": prompt})
     tok = jnp.argmax(logits, -1).astype(jnp.int32)
-    st_r, tok_r, logits_r = jax.jit(make_decode_step(cfg5))(
+    st_r, tok_r, logits_r = make_decode_step(cfg5)(params, state, tok)
+    _, tok_p, logits_p, lv = make_decode_step(cfg5, progressive=True)(
         params, state, tok)
-    _, tok_p, logits_p, lv = jax.jit(make_decode_step(
-        cfg5, progressive=True))(params, state, tok)
     np.testing.assert_array_equal(np.asarray(logits_p),
                                   np.asarray(logits_r))
     np.testing.assert_array_equal(np.asarray(tok_p), np.asarray(tok_r))
