@@ -1,0 +1,9 @@
+"""Least compute time of the model work of the mean prefill execution
+(weight matmuls at the int8 peak, attention at each row's true context
+at the bf16 peak) over the mean device time of a prefill execution."""
+
+from bench.reduce import step_mfu
+
+
+def read(rec):
+    return step_mfu(rec, "prefill")
