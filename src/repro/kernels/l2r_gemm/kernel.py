@@ -212,7 +212,8 @@ def _l2r_stacked_kernel(a_idx_ref, b_idx_ref, a_ref, b_ref, o_ref, acc_ref,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n_bits", "log2_radix", "levels", "bm", "bk", "bn", "interpret"),
+    static_argnames=("n_bits", "log2_radix", "levels", "bm", "bk", "bn",
+                     "interpret", "name"),
 )
 def l2r_gemm_pallas_stacked_planes(
     a_stack: jax.Array,
@@ -224,6 +225,7 @@ def l2r_gemm_pallas_stacked_planes(
     bk: int = 256,
     bn: int = 128,
     interpret: bool = False,
+    name: str | None = None,
 ) -> jax.Array:
     """Level-stacked MSDF GEMM over PRE-STACKED plane operands.
 
@@ -237,6 +239,10 @@ def l2r_gemm_pallas_stacked_planes(
     taps, per-decode-step weight matmuls) extract planes once and call
     this entry per GEMM — the hoist the jnp backend already performs,
     now available to the TPU kernel (ROADMAP follow-up).
+
+    ``name`` tags the kernel in device traces: the call's HLO
+    instruction becomes ``l2r_gemm_pallas_stacked_planes_<name>`` (the
+    caller's layer), where it is otherwise this function's name.
     """
     m, dk = a_stack.shape
     dk2, n = b_rev.shape
@@ -268,6 +274,7 @@ def l2r_gemm_pallas_stacked_planes(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
+        name=f"l2r_gemm_pallas_stacked_planes_{name}" if name else None,
     )(jnp.asarray(a_idx), jnp.asarray(b_idx), a_stack, b_rev)
 
 

@@ -204,7 +204,7 @@ def _gemm_mk(a) -> tuple[int, int]:
 @functools.partial(
     jax.jit,
     static_argnames=("n_bits", "log2_radix", "levels", "bm", "bk", "bn",
-                     "schedule", "backend", "early_exit"),
+                     "schedule", "backend", "early_exit", "name"),
 )
 def _l2r_gemm_backend(
     aq: jax.Array,
@@ -218,6 +218,7 @@ def _l2r_gemm_backend(
     schedule: str,
     backend: str,
     early_exit: bool = False,
+    name: str | None = None,
 ) -> jax.Array:
     """Backend-resolved integer GEMM (backend is a static, already-resolved
     string here so the trace cache keys on it).  Either operand may be a
@@ -261,7 +262,7 @@ def _l2r_gemm_backend(
     b_rev, n = _rhs_stack_blocked(bq, n_bits, log2_radix, bk, bn)
     out = l2r_gemm_pallas_stacked_planes(a_stack, b_rev, n_bits, log2_radix,
                                          levels, bm, bk, bn,
-                                         interpret=interpret)
+                                         interpret=interpret, name=name)
     return out[:m, :n]
 
 
@@ -301,6 +302,7 @@ def l2r_gemm(
     schedule: str = "stacked",
     backend: str | None = None,
     early_exit: bool = False,
+    name: str | None = None,
 ) -> jax.Array:
     """Integer MSDF GEMM with backend dispatch. (M,K)x(K,N) -> int32.
 
@@ -325,6 +327,10 @@ def l2r_gemm(
     and the Pallas grids cannot shrink at runtime — their analogue is the
     streaming kernel's dynamic ``level_count`` scalar
     (kernel.py:l2r_gemm_pallas_streaming).
+
+    ``name`` tags the stacked Pallas kernel in device traces
+    (kernel.py:l2r_gemm_pallas_stacked_planes); results never depend
+    on it.
     """
     assert schedule in SCHEDULES, schedule
     if early_exit and schedule != "streaming":
@@ -358,7 +364,7 @@ def l2r_gemm(
     _certify(n_bits, log2_radix, int(k), levels=levels, where="l2r_gemm")
     return _l2r_gemm_backend(aq, bq, n_bits, log2_radix, levels,
                              bm, bk, bn, schedule, resolved,
-                             early_exit)
+                             early_exit, name)
 
 
 @functools.partial(
@@ -546,6 +552,7 @@ def l2r_matmul_f(
     w_q: QuantizedWeights | tuple[jax.Array, jax.Array] | None = None,
     backend: str | None = None,
     schedule: str = "stacked",
+    name: str | None = None,
 ) -> jax.Array:
     """Float -> quantize -> dispatched MSDF GEMM -> dequantized float.
 
@@ -554,7 +561,8 @@ def l2r_matmul_f(
     the cache also carries its pre-stacked RHS plane stack
     (``quantize_weights(..., prestack=True)``) and the layout matches
     this call's config, the GEMM consumes the stack directly — weight
-    plane extraction then happened exactly once at load time.
+    plane extraction then happened exactly once at load time.  ``name``
+    tags the kernel in device traces (:func:`l2r_gemm`).
     """
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
@@ -573,7 +581,8 @@ def l2r_matmul_f(
     else:
         wq, ws = w_q
     out = l2r_gemm(xq, wq if w_in is None else w_in, cfg.n_bits,
-                   cfg.log2_radix, levels, schedule=schedule, backend=backend)
+                   cfg.log2_radix, levels, schedule=schedule, backend=backend,
+                   name=name)
     out = out.astype(jnp.float32) * xs * ws.reshape(1, -1)
     return out.astype(x.dtype).reshape(*lead, wq.shape[-1])
 
@@ -623,7 +632,7 @@ def _conv_wrev(w_in, n_bits: int, log2_radix: int, shifted: bool) -> jax.Array:
 @functools.partial(
     jax.jit,
     static_argnames=("n_bits", "log2_radix", "levels", "backend", "stride",
-                     "dilation"),
+                     "dilation", "name"),
 )
 def _l2r_conv2d_int(
     xq: jax.Array,
@@ -634,6 +643,7 @@ def _l2r_conv2d_int(
     backend: str,
     stride: tuple[int, int] = (1, 1),
     dilation: tuple[int, int] = (1, 1),
+    name: str | None = None,
 ) -> jax.Array:
     """Integer core of the fused conv: implicit im2col over kh*kw taps.
 
@@ -696,7 +706,8 @@ def _l2r_conv2d_int(
             a2 = jnp.pad(a2, (((0, (-m0) % 128), (0, 0), (0, ckp - cin))))
             t = l2r_gemm_pallas_stacked_planes(
                 a2.reshape(a2.shape[0], -1), wrev[dy, dx], n_bits,
-                log2_radix, levels, 128, bk, 128, interpret=interpret)
+                log2_radix, levels, 128, bk, 128, interpret=interpret,
+                name=name)
             acc = acc + t[:m0, :cout].reshape(bsz, oh, ow, cout)
     return acc
 
@@ -711,6 +722,7 @@ def l2r_conv2d(
     backend: str | None = None,
     stride: int | tuple[int, int] = 1,
     dilation: int | tuple[int, int] = 1,
+    name: str | None = None,
 ) -> jax.Array:
     """Fused L2R conv2d, NHWC/HWIO, "SAME" padding, any stride/dilation.
 
@@ -723,12 +735,14 @@ def l2r_conv2d(
     pre-stacked plane stack (``quantize_weights(..., prestack=True,
     plane_axis=-2)``) the conv consumes that stack directly and performs
     no weight plane extraction at all; otherwise ``w`` (kh, kw, cin,
-    cout) is quantized per output channel here.
+    cout) is quantized per output channel here.  ``name`` tags the tap
+    kernels in device traces (:func:`l2r_gemm`).
     """
     if w_q is None:
         w_q = quantize_weights(w, cfg)  # (kh,kw,cin,cout), scale (1,1,1,cout)
     xq, xs = quantize(x, cfg, axis=0)  # per-image scales (B,1,1,1)
-    out = l2r_conv2d_int(xq, w_q, cfg, levels, backend, stride, dilation)
+    out = l2r_conv2d_int(xq, w_q, cfg, levels, backend, stride, dilation,
+                         name)
     out = out.astype(jnp.float32) * xs * w_q.scale.reshape(1, 1, 1, -1)
     out = out.astype(x.dtype)
     if b is not None:
@@ -744,6 +758,7 @@ def l2r_conv2d_int(
     backend: str | None = None,
     stride: int | tuple[int, int] = 1,
     dilation: int | tuple[int, int] = 1,
+    name: str | None = None,
 ) -> jax.Array:
     """Integer core of :func:`l2r_conv2d`: quantized activations ``xq``
     (B, H, W, cin) against the weight cache ``w_q`` -> int32 (B, OH, OW,
@@ -755,7 +770,7 @@ def l2r_conv2d_int(
     return _l2r_conv2d_int(xq, _conv_w_in(w_q, cfg), cfg.n_bits,
                            cfg.log2_radix, levels,
                            resolve_backend(backend, cfg.n_bits),
-                           _pair(stride), _pair(dilation))
+                           _pair(stride), _pair(dilation), name)
 
 
 def _pair(v: int | tuple[int, int]) -> tuple[int, int]:

@@ -88,7 +88,8 @@ def main(argv=None):
               f"dispatches + {st['prefills']} prefill dispatches "
               f"(buckets {st['buckets']}); {st['tokens_per_s']:.1f} tok/s, "
               f"ttft_p50 {st['ttft_p50_s'] * 1e3:.1f} ms, "
-              f"tpot_p50 {st['tpot_p50_s'] * 1e3:.1f} ms")
+              f"tpot_p50 {st['tpot_p50_s'] * 1e3:.1f} ms, "
+              f"{st['decode_in_flight_mean']:.2f} decode steps in flight")
         seqs = np.asarray([r.output for r in reqs])
         for i, row in enumerate(seqs):
             print(f"seq{i}: {row.tolist()}")

@@ -113,26 +113,34 @@ def vgg16_apply(
     """
     x, weights_q = _vgg16_trunk(params, images, l2r, levels, weights_q,
                                 backend)
-    if l2r is not None:
-        return l2r_matmul_f(x, None, l2r, levels, w_q=weights_q["fc8"],
-                            backend=backend) + params["fc8"]["b"]
-    return x @ params["fc8"]["w"].astype(x.dtype) + params["fc8"]["b"]
+    with jax.named_scope("fc8"):
+        if l2r is not None:
+            return l2r_matmul_f(x, None, l2r, levels, w_q=weights_q["fc8"],
+                                backend=backend, name="fc8") \
+                + params["fc8"]["b"]
+        return x @ params["fc8"]["w"].astype(x.dtype) + params["fc8"]["b"]
 
 
 def _vgg16_trunk(params, images, l2r, levels, weights_q, backend):
     """Everything up to the fc8 classifier head: (fc7 activations,
-    weights_q).  Shared by the one-shot and progressive classify paths."""
+    weights_q).  Shared by the one-shot and progressive classify paths.
+    Each layer runs under a ``jax.named_scope`` of its name (``conv1_1``
+    .. ``fc7``), and its L2R kernel is named after it
+    (``l2r_gemm_pallas_stacked_planes_conv1_1``), so a device trace
+    puts kernel time on layers."""
     x = images
     if l2r is not None and weights_q is None:
         weights_q = vgg16_quantize_weights(params, l2r)
     if l2r is not None:
         conv = lambda x, p, name: l2r_conv2d(
-            x, None, p["b"], l2r, levels, w_q=weights_q[name], backend=backend)
+            x, None, p["b"], l2r, levels, w_q=weights_q[name], backend=backend,
+            name=name)
     else:
         conv = lambda x, p, name: _conv_float(x, p["w"], p["b"])
     stage_splits = {1: 2, 3: 2, 6: 2, 9: 2, 12: 2}  # pool after these conv idxs
     for i, layer in enumerate(VGG16_CONV_LAYERS):
-        x = jax.nn.relu(conv(x, params[layer.name], layer.name))
+        with jax.named_scope(layer.name):
+            x = jax.nn.relu(conv(x, params[layer.name], layer.name))
         if i in stage_splits:
             x = jax.lax.reduce_window(
                 x, -jnp.inf, jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1), "VALID"
@@ -145,11 +153,14 @@ def _vgg16_trunk(params, images, l2r, levels, weights_q, backend):
     flat = x.reshape(bsz, -1)
     if l2r is not None:
         mm = lambda a, name: l2r_matmul_f(
-            a, None, l2r, levels, w_q=weights_q[name], backend=backend)
+            a, None, l2r, levels, w_q=weights_q[name], backend=backend,
+            name=name)
     else:
         mm = lambda a, name: a @ params[name]["w"].astype(a.dtype)
-    x = jax.nn.relu(mm(flat, "fc6") + params["fc6"]["b"])
-    x = jax.nn.relu(mm(x, "fc7") + params["fc7"]["b"])
+    x = flat
+    for name in ("fc6", "fc7"):
+        with jax.named_scope(name):
+            x = jax.nn.relu(mm(x, name) + params[name]["b"])
     return x, weights_q
 
 
@@ -199,8 +210,9 @@ def vgg16_classify_progressive(
     p = w_q.planes
     wq_in = p if (p is not None and p.matches(l2r.n_bits, l2r.log2_radix,
                                               ndim=2, side="rhs")) else w_q.q
-    logits, pred, exit_level = streaming_argmax(
-        xq, wq_in, xs, w_q.scale, l2r.n_bits, l2r.log2_radix,
-        bias=params["fc8"]["b"], out_dtype=x.dtype, early_exit=early_exit,
-        mesh=mesh)
+    with jax.named_scope("fc8"):
+        logits, pred, exit_level = streaming_argmax(
+            xq, wq_in, xs, w_q.scale, l2r.n_bits, l2r.log2_radix,
+            bias=params["fc8"]["b"], out_dtype=x.dtype,
+            early_exit=early_exit, mesh=mesh)
     return pred, exit_level, logits

@@ -61,6 +61,7 @@ def attn_build(cfg: ModelConfig, cross: bool = False) -> dict:
     return p
 
 
+@jax.named_scope("attention")
 def attn_apply(
     cfg: ModelConfig,
     p: dict,
@@ -73,7 +74,9 @@ def attn_apply(
     window: int | None,
     cross_kv: tuple[jax.Array, jax.Array] | None = None,
 ):
-    """Self- or cross-attention layer.  Returns (out, new_cache)."""
+    """Self- or cross-attention layer.  Returns (out, new_cache).  Runs
+    under the ``attention`` named scope, its cache write under
+    ``kv_cache_update``."""
     b, s, _ = x.shape
     h, kv, dh = cfg.n_heads, cfg.n_kv, cfg.head_dim
 
@@ -105,7 +108,9 @@ def attn_apply(
     k = apply_rope(k, rope_positions, cfg.rope_theta, cfg.rope_mode, cfg.mrope_sections)
 
     if mode == "decode":
-        cache = update_kv_cache(cache, k, v, positions, quant=cfg.attn_l2r)
+        with jax.named_scope("kv_cache_update"):
+            cache = update_kv_cache(cache, k, v, positions,
+                                    quant=cfg.attn_l2r)
         out = decode_attention(
             q, cache.k, cache.v, cache.positions, positions[:, 0],
             window=window, scale=cfg.attn_scale, softcap=cfg.logit_softcap,
@@ -117,8 +122,9 @@ def attn_apply(
         if mode == "prefill":
             # a plane-stacked cache fills incrementally here too: decode
             # steps after this prefill consume a ready operand
-            cache = update_kv_cache(cache, k, v, positions,
-                                    quant=cfg.attn_l2r)
+            with jax.named_scope("kv_cache_update"):
+                cache = update_kv_cache(cache, k, v, positions,
+                                        quant=cfg.attn_l2r)
         out = chunked_attention(
             q, k, v, causal=True, window=window, scale=cfg.attn_scale,
             softcap=cfg.logit_softcap,
@@ -217,9 +223,11 @@ def layer_apply(
     if ffn_kind != "none":
         h = norm(x, params["ffn_norm"])
         if ffn_kind == "moe":
-            out, aux = moe_apply(cfg, params["ffn"], h)
+            with jax.named_scope("moe"):
+                out, aux = moe_apply(cfg, params["ffn"], h)
         else:
-            out = mlp_apply(cfg, params["ffn"], h)
+            with jax.named_scope("mlp"):
+                out = mlp_apply(cfg, params["ffn"], h)
         x = x + out
     return x, new_cache, aux
 
@@ -360,16 +368,21 @@ def lm_forward(
     if repeats:
         # Caches ride the scan CARRY and are updated in place with
         # dynamic_update_index_in_dim: XLA aliases while-loop carries, so
-        # decode/prefill never copies the full stacked KV cache (the
-        # xs/ys formulation materialized a whole-cache copy per step —
-        # 42% of baseline decode HBM traffic; EXPERIMENTS.md §Perf).
+        # decode/prefill never copies the full stacked KV cache at once
+        # (the xs/ys formulation materialized a whole-cache copy per
+        # step — 42% of baseline decode HBM traffic; EXPERIMENTS.md
+        # §Perf).  Each block still copies its layer's cache out of the
+        # carry and back: the ``kv_cache_read`` / ``kv_cache_writeback``
+        # scopes, 46% of decode device time at 64 slots x 1024 on a TPU
+        # v5e (PERF.md §5).
         def block(carry, lp):
             x, aux_acc, caches_all, blk_i = carry
             if caches_all is not None:
-                caches = jax.tree.map(
-                    lambda buf: jax.lax.dynamic_index_in_dim(
-                        buf, blk_i, 0, keepdims=False),
-                    caches_all)
+                with jax.named_scope("kv_cache_read"):
+                    caches = jax.tree.map(
+                        lambda buf: jax.lax.dynamic_index_in_dim(
+                            buf, blk_i, 0, keepdims=False),
+                        caches_all)
             new_caches = []
             for u_idx, kk in enumerate(unit):
                 x, c2, aux = run_layer(
@@ -379,10 +392,11 @@ def lm_forward(
                 aux_acc = aux_acc + aux
             x = resid_shard(x)
             if caches_all is not None:
-                caches_all = jax.tree.map(
-                    lambda buf, new: jax.lax.dynamic_update_index_in_dim(
-                        buf, new.astype(buf.dtype), blk_i, 0),
-                    caches_all, new_caches)
+                with jax.named_scope("kv_cache_writeback"):
+                    caches_all = jax.tree.map(
+                        lambda buf, new: jax.lax.dynamic_update_index_in_dim(
+                            buf, new.astype(buf.dtype), blk_i, 0),
+                        caches_all, new_caches)
             return (x, aux_acc, caches_all, blk_i + 1), None
 
         block_fn = jax.checkpoint(block) if remat else block
@@ -412,13 +426,15 @@ def lm_forward(
     return x, new_state, aux_total
 
 
+@jax.named_scope("head")
 def logits_from_hidden(cfg: ModelConfig, params: dict, hidden: jax.Array) -> jax.Array:
     """LM head.  With an L2R config the head matmul runs through the
     digit-plane pipeline like every other matmul — which also makes it
     streamable level-by-level (serve/engine.py progressive decode commits
     tokens bit-identically to this full evaluation).  A ``head_q`` cache
     entry (serve/engine.py:prepare_params) skips the per-step head-weight
-    quantization on serving paths."""
+    quantization on serving paths.  Runs under the ``head`` named
+    scope."""
     if cfg.l2r is not None and "head_q" in params:
         return dense(hidden, params["head_q"], cfg.l2r, cfg.l2r_levels)
     if cfg.tie_embeddings:

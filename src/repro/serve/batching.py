@@ -110,10 +110,13 @@ class Request:
     # latency timestamps (time.perf_counter seconds).  ``t_arrival`` is
     # stamped at submit() unless the caller pre-stamped it (traffic
     # replay: a Poisson generator stamps the synthetic arrival instant);
+    # ``t_admit`` (the gateway only) when its prefill is dispatched,
     # ``t_first_token`` when the first token is committed,
     # ``t_complete`` at retirement.  TTFT = t_first_token - t_arrival,
-    # mean TPOT = (t_complete - t_first_token) / (len(output) - 1).
+    # mean TPOT = (t_complete - t_first_token) / (len(output) - 1);
+    # t_admit splits TTFT into queue wait and first-token lag.
     t_arrival: float | None = None
+    t_admit: float | None = None
     t_first_token: float | None = None
     t_complete: float | None = None
 

@@ -497,6 +497,7 @@ def make_bucket_prefill_step(cfg: ModelConfig, max_len: int,
     return _serve_jit(prefill, jit_kwargs)
 
 
+@jax.named_scope("head")
 def progressive_logits_from_hidden(cfg: ModelConfig, params, hidden,
                                    early_exit: bool = False,
                                    mesh: Mesh | None = None,
@@ -526,7 +527,7 @@ def progressive_logits_from_hidden(cfg: ModelConfig, params, hidden,
     (decode: one per batch slot) — threaded straight into the shared
     decision fold; ``exact`` rows roundtrip the full stream, ``budget``
     rows clamp at their level, ``bounded`` rows early-commit at their
-    own tolerance.
+    own tolerance.  Runs under the ``head`` named scope.
     """
     qcfg = cfg.l2r or QuantConfig()
     if "head_q" in params:  # the prepare_params load-time head cache

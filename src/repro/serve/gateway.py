@@ -36,6 +36,20 @@ three per-request / per-step taxes that dominate at fleet scale:
     back as a ``(slot, generation)`` pair — the generation counter
     keeps a stale signal from freeing a reassigned slot.
 
+Measurement is always on and costs a clock read or a counter per
+dispatch.  ``Request.t_admit`` stamps the dispatch of a request's
+prefill, so TTFT splits into queue wait (``t_admit - t_arrival``) and
+first-token lag (``t_first_token - t_admit``: device backlog, prefill,
+emit).  ``stats()["decode_in_flight_mean"]`` is the mean number of
+decode steps dispatched but not yet landed at each decode dispatch: how
+far the loop runs ahead of the device.  ``jax.profiler.TraceAnnotation``
+spans, which record only inside an active profile, put the host loop on
+the device trace's clock: ``gateway.admit`` (one prefill group, with its
+request uids), ``gateway.decode`` (one decode dispatch),
+``gateway.wait_arrival`` (the realtime sleep), ``gateway.flush`` (a wait
+for the emit thread) and, on the emit thread, ``gateway.emit`` (one
+item, with its kind and, for a prefill, its uids).
+
 Output streams are bit-identical to `ContinuousBatcher` for the same
 request set (tests/test_gateway.py): bucketed prefill is bit-exact,
 rows of a packed prefill are independent, and decode rows are
@@ -44,6 +58,7 @@ independent, so batching composition cannot move a token.
 
 from __future__ import annotations
 
+import functools
 import queue
 import threading
 import time
@@ -51,6 +66,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.policy import LevelPolicy, PrecisionClass
@@ -62,6 +78,12 @@ from .engine import (bucket_for, make_bucket_prefill_step, make_decode_step,
                      prefill_buckets, supports_bucketed_prefill)
 
 __all__ = ["ServingGateway"]
+
+
+def _uids(reqs) -> str:
+    """Request uids as one span argument (the profiler's argument
+    encoding reserves ``,`` and ``#``)."""
+    return " ".join(str(r.uid) for r in reqs)
 
 
 class _EmitThread:
@@ -219,6 +241,11 @@ class ServingGateway:
         self.queue: list[Request] = []
         self.steps = 0
         self.prefills = 0
+        # decode steps in flight at each decode dispatch, as sum and
+        # count; ``_decodes_landed`` is written by the emit thread only
+        self._decodes_landed = 0
+        self._in_flight_sum = 0
+        self._in_flight_n = 0
 
         # emit-side accounting (owned by the emit thread; read after
         # flush())
@@ -318,7 +345,8 @@ class ServingGateway:
                     nxt = min(r.t_arrival for r in self.queue)
                     dt = nxt - time.perf_counter()
                     if dt > 0:
-                        time.sleep(min(dt, 0.05))
+                        with TraceAnnotation("gateway.wait_arrival"):
+                            time.sleep(min(dt, 0.05))
                     continue
                 # EOS-retirement lag can leave every slot waiting on the
                 # emit thread while the queue still holds work
@@ -332,7 +360,8 @@ class ServingGateway:
 
     def stats(self, latency: bool = True) -> dict:
         """Gateway counters (emit-thread flushed first): dispatch and
-        token counts, throughput, progressive saved-levels histograms
+        token counts, throughput, the mean number of decode steps in
+        flight at a decode dispatch, progressive saved-levels histograms
         (same schema as `ContinuousBatcher.stats`), and — unless
         ``latency=False`` — p50/p99 TTFT and per-output-token seconds
         over completed requests."""
@@ -342,7 +371,10 @@ class ServingGateway:
                "completed": self._completed,
                "buckets": list(self.buckets),
                "tokens_per_s": (self._tokens / self._elapsed
-                                if self._elapsed > 0 else 0.0)}
+                                if self._elapsed > 0 else 0.0),
+               "decode_in_flight_mean": (
+                   self._in_flight_sum / self._in_flight_n
+                   if self._in_flight_n else 0.0)}
         if self.progressive:
             out.update(progressive_stats(self.n_levels, self.exit_hist,
                                          self.prefill_exit_hist,
@@ -387,51 +419,59 @@ class ServingGateway:
                     group.append(r)
             for r in group:
                 self.queue.remove(r)
+            with TraceAnnotation("gateway.admit", uids=_uids(group)):
+                self._prefill_group(group, free, lb)
 
-            g = self.prefill_group
-            tokens = np.zeros((g, lb), np.int32)
-            true_len = np.ones((g,), np.int32)  # dummy rows: one pad token
-            for i, r in enumerate(group):
-                p = np.asarray(r.prompt, np.int32)
-                tokens[i, :len(p)] = p
-                true_len[i] = len(p)
-            exe = self._prefill_exe.get(lb, self._prefill_jit)
-            if self.progressive:
-                # per-row group policy: admitted requests' classes,
-                # dummy pad rows at the default class
-                gcls = [self._class_of(r) for r in group]
-                gcls += [self.default_class] * (g - len(group))
-                out = exe(self.params, jnp.asarray(tokens),
-                          jnp.asarray(true_len),
-                          LevelPolicy.from_classes(gcls))
-            else:
-                out = exe(self.params, jnp.asarray(tokens),
-                          jnp.asarray(true_len))
-            if self.progressive:
-                st1, _, tok, lv = out
-            else:
-                st1, logits = out
-                tok = jnp.argmax(logits[:, -1], axis=-1,
-                                 keepdims=True).astype(jnp.int32)
-                lv = None
-            self.prefills += 1
+    def _prefill_group(self, group: list, free: list, lb: int):
+        """Dispatch one packed prefill of ``group`` at bucket ``lb`` and
+        splice its rows into the ``free`` slots."""
+        g = self.prefill_group
+        tokens = np.zeros((g, lb), np.int32)
+        true_len = np.ones((g,), np.int32)  # dummy rows: one pad token
+        for i, r in enumerate(group):
+            p = np.asarray(r.prompt, np.int32)
+            tokens[i, :len(p)] = p
+            true_len[i] = len(p)
+        exe = self._prefill_exe.get(lb, self._prefill_jit)
+        if self.progressive:
+            # per-row group policy: admitted requests' classes,
+            # dummy pad rows at the default class
+            gcls = [self._class_of(r) for r in group]
+            gcls += [self.default_class] * (g - len(group))
+            out = exe(self.params, jnp.asarray(tokens),
+                      jnp.asarray(true_len),
+                      LevelPolicy.from_classes(gcls))
+        else:
+            out = exe(self.params, jnp.asarray(tokens),
+                      jnp.asarray(true_len))
+        t_admit = time.perf_counter()
+        for r in group:
+            r.t_admit = t_admit
+        if self.progressive:
+            st1, _, tok, lv = out
+        else:
+            st1, logits = out
+            tok = jnp.argmax(logits[:, -1], axis=-1,
+                             keepdims=True).astype(jnp.int32)
+            lv = None
+        self.prefills += 1
 
-            entries = []
-            for i, r in enumerate(group):
-                slot = free[i]
-                s = self._slots[slot]
-                s.req = r
-                s.rem = self._budget_steps(r)
-                row = jax.tree.map(
-                    lambda x, a: jax.lax.slice_in_dim(x, i, i + 1, axis=a)
-                    if a >= 0 else x, st1, self._axes)
-                self.state = _splice(self.state, row, slot, self._axes)
-                self.cur_tok = self.cur_tok.at[slot, 0].set(tok[i, 0])
-                if self.progressive:
-                    self.slot_policy = self.slot_policy.set_row(
-                        slot, self._class_of(r))
-                entries.append((i, slot, s.gen, r))
-            self._dispatch_emit(("prefill", entries, tok, lv))
+        entries = []
+        for i, r in enumerate(group):
+            slot = free[i]
+            s = self._slots[slot]
+            s.req = r
+            s.rem = self._budget_steps(r)
+            row = jax.tree.map(
+                lambda x, a: jax.lax.slice_in_dim(x, i, i + 1, axis=a)
+                if a >= 0 else x, st1, self._axes)
+            self.state = _splice(self.state, row, slot, self._axes)
+            self.cur_tok = self.cur_tok.at[slot, 0].set(tok[i, 0])
+            if self.progressive:
+                self.slot_policy = self.slot_policy.set_row(
+                    slot, self._class_of(r))
+            entries.append((i, slot, s.gen, r))
+        self._dispatch_emit(("prefill", entries, tok, lv))
 
     def _budget_steps(self, req: Request) -> int:
         """Decode steps owed to a request AFTER its prefill token,
@@ -444,7 +484,10 @@ class ServingGateway:
         return max(1, min(req.max_new_tokens - 1,
                           self.max_len - 1 - len(req.prompt)))
 
+    @functools.partial(jax.profiler.annotate_function, name="gateway.decode")
     def _decode_step(self):
+        self._in_flight_sum += self.steps - self._decodes_landed
+        self._in_flight_n += 1
         if self.progressive:
             out = (self._decode_exe or self._decode_jit)(
                 self.params, self.state, self.cur_tok, None,
@@ -497,7 +540,8 @@ class ServingGateway:
 
     def _flush_emit(self):
         if self._emit is not None:
-            self._emit.flush()
+            with TraceAnnotation("gateway.flush"):
+                self._emit.flush()
 
     def _process_emit(self, item):
         """Host-side token landing (emit thread): sync the device
@@ -505,6 +549,12 @@ class ServingGateway:
         detect EOS.  ``entries`` rows are (row-in-dispatch, slot, gen,
         req) for prefill and (slot, gen, req) for decode."""
         kind, entries, tok, lv = item
+        uids = {"uids": _uids(e[3] for e in entries)} \
+            if kind == "prefill" else {}
+        with TraceAnnotation("gateway.emit", kind=kind, **uids):
+            self._land_item(kind, entries, tok, lv)
+
+    def _land_item(self, kind, entries, tok, lv):
         tok = np.asarray(tok).reshape(-1)
         lv = np.asarray(lv).reshape(-1) if lv is not None else None
         now = time.perf_counter()
@@ -529,6 +579,7 @@ class ServingGateway:
                     self._class_hist(self.exit_hist_by_class,
                                      self._class_of(req).label())[level] += 1
                 self._land(req, int(tok[slot]), slot, gen)
+            self._decodes_landed += 1
 
     def _land(self, req: Request, t: int, slot: int, gen: int):
         req.output.append(t)

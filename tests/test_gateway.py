@@ -267,3 +267,74 @@ def test_gateway_realtime_honors_arrival_stamps(model):
     for a, b in zip(offline, online):
         assert a.output == b.output
         assert b.t_first_token >= b.t_arrival
+
+
+# ------------------------------------------------- stamps and counters
+@pytest.mark.parametrize("realtime", [False, True], ids=["offline", "realtime"])
+def test_gateway_request_stamps_and_in_flight(model, realtime):
+    """Every served request is stamped in order arrival <= admission <=
+    first token <= completion; the requests of one packed prefill share
+    one admission stamp (and one first-token stamp); the decode steps in
+    flight at a dispatch never exceed what the emit queue can hold plus
+    the item on the emit thread."""
+    import time
+
+    cfg, params = model
+    depth = 2
+    reqs = _mixed_requests(cfg, (5, 9, 4, 12, 7, 6, 3), max_new=5, seed=4)
+    gw = ServingGateway(cfg, params, n_slots=3, max_len=32,
+                        prefill_group=2, emit_queue_depth=depth)
+    t0 = time.perf_counter()
+    for i, r in enumerate(reqs):
+        r.t_arrival = t0 + (0.01 * i if realtime else 0.0)
+        gw.submit(r)
+    gw.run(realtime=realtime)
+    st = gw.stats()
+    gw.close()
+    for r in reqs:
+        assert r.done
+        assert r.t_arrival <= r.t_admit <= r.t_first_token <= r.t_complete
+    by_admit: dict[float, set] = {}
+    by_first: dict[float, set] = {}
+    for r in reqs:
+        by_admit.setdefault(r.t_admit, set()).add(r.uid)
+        by_first.setdefault(r.t_first_token, set()).add(r.uid)
+    assert len(by_admit) == st["prefills"]
+    assert sorted(map(sorted, by_admit.values())) == \
+        sorted(map(sorted, by_first.values()))
+    assert all(len(g) <= 2 for g in by_admit.values())
+    assert 0.0 <= st["decode_in_flight_mean"] <= depth + 1
+
+
+def test_gateway_sync_emit_has_nothing_in_flight(model):
+    """Inline emit lands every decode before the next dispatch."""
+    cfg, params = model
+    gw = ServingGateway(cfg, params, n_slots=2, max_len=32,
+                        prefill_group=2, async_emit=False)
+    gw.run(_mixed_requests(cfg, (5, 9, 4), max_new=4))
+    st = gw.stats()
+    gw.close()
+    assert st["steps"] > 0 and st["decode_in_flight_mean"] == 0.0
+
+
+def _check_stats_schema_fixed(cfg, params, progressive):
+    gw = ServingGateway(cfg, params, n_slots=2, max_len=32,
+                        prefill_group=2, progressive=progressive)
+    before = set(gw.stats())
+    assert "decode_in_flight_mean" in before
+    gw.run(_mixed_requests(cfg, (5, 9, 4), max_new=4))
+    after = gw.stats()
+    gw.close()
+    assert set(after) == before
+    assert after["decode_in_flight_mean"] >= 0.0
+
+
+def test_gateway_stats_schema_fixed(model):
+    """stats() has the same keys from construction on, whatever was
+    served."""
+    _check_stats_schema_fixed(*model, progressive=False)
+
+
+def test_gateway_progressive_stats_schema_fixed(prog_model):
+    """The same with the progressive head's histograms."""
+    _check_stats_schema_fixed(*prog_model, progressive=True)

@@ -73,3 +73,25 @@ def test_progressive_precision_serving_is_exact_at_full_levels():
     cfg_l3 = dataclasses.replace(cfg, l2r=QuantConfig(), l2r_levels=4)
     h_p, _, _ = lm_forward(cfg_l3, params, tokens=toks, mode="train")
     assert np.isfinite(np.asarray(h_p, np.float32)).all()
+
+
+def test_decode_step_scopes_in_lowered_text():
+    """The decode step's attention, KV-cache read, update and write-back,
+    MLP and head carry named scopes in the compiled program's op_name
+    metadata."""
+    import re
+
+    cfg = dataclasses.replace(get_smoke("smollm-135m"), l2r=QuantConfig())
+    params = materialize(lm_build(cfg), jax.random.PRNGKey(0))
+    st = init_lm_state(cfg, 2, max_len=16, dtype=jnp.float32)
+    tok = jnp.zeros((2, 1), jnp.int32)
+    text = make_decode_step(cfg).lower(params, st, tok).compile().as_text()
+    scoped = set()
+    for name in re.findall(r'op_name="([^"]*)"', text):
+        parts = name.split("/")
+        scoped.update((p, parts[-1]) for p in parts[:-1])
+    for scope in ("attention", "mlp", "head"):
+        assert (scope, "dot_general") in scoped, scope
+    assert ("kv_cache_read", "dynamic_slice") in scoped
+    assert ("kv_cache_update", "scatter") in scoped
+    assert ("kv_cache_writeback", "dynamic_update_slice") in scoped

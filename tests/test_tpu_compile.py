@@ -104,3 +104,25 @@ def test_pair_loop_kernel(one_chip):
     """The D^2 pair-loop baseline feeds int8 digit planes to the MXU."""
     fn = lambda a, b: l2r_gemm_pallas(a, b)
     _compile(fn, one_chip, ((256, 512), jnp.int8), ((512, 256), jnp.int8))
+
+
+def test_kernels_carry_their_layer_name(one_chip):
+    """A layer-named conv and fc GEMM compile to kernels named
+    ``l2r_gemm_pallas_stacked_planes_<layer>``: the device trace puts
+    their time on the layer, and the name keeps the ``l2r_gemm_pallas``
+    every kernel-roofline reader matches."""
+    import re
+
+    from repro.kernels.l2r_gemm.ops import _l2r_conv2d_int, _l2r_gemm_backend
+
+    conv = lambda x, w: _l2r_conv2d_int(x, w, 8, 2, None, "pallas-tpu",
+                                        name="conv1_1")
+    text = _compile(conv, one_chip, ((1, 16, 16, 3), jnp.int8),
+                    ((3, 3, 3, 64), jnp.int8))
+    assert re.search(r"%l2r_gemm_pallas_stacked_planes_conv1_1(\.\d+)? = ",
+                     text)
+    fc = lambda a, b: _l2r_gemm_backend(a, b, 8, 2, None, 128, 256, 128,
+                                        "stacked", "pallas-tpu", name="fc6")
+    text = _compile(fc, one_chip, ((8, 512), jnp.int8),
+                    ((512, 256), jnp.int8))
+    assert re.search(r"%l2r_gemm_pallas_stacked_planes_fc6(\.\d+)? = ", text)

@@ -56,3 +56,34 @@ def test_l2r_radix16_exact_match(setup):
     r4 = np.asarray(vgg16_apply(params, img, l2r=QuantConfig(log2_radix=2)))
     r16 = np.asarray(vgg16_apply(params, img, l2r=QuantConfig(log2_radix=4)))
     np.testing.assert_allclose(r4, r16, atol=1e-4)
+
+
+def _scoped_ops(lowered) -> set[tuple[str, str]]:
+    """(scope component, primitive) of every op of a lowered program, from
+    the ``op_name`` metadata of the compiled module (where inner jits are
+    inlined, so each op carries its caller's scopes)."""
+    import re
+
+    out = set()
+    for name in re.findall(r'op_name="([^"]*)"',
+                           lowered.compile().as_text()):
+        parts = name.split("/")
+        out.update((p, parts[-1]) for p in parts[:-1])
+    return out
+
+
+def test_layer_scopes_in_lowered_forward(setup):
+    """Each layer's matmuls carry the layer's named scope, conv1_1 to fc8,
+    so a device trace can put their time on the layer."""
+    from repro.core.cycle_model import VGG16_CONV_LAYERS
+    from repro.models.cnn import vgg16_quantize_weights
+
+    params, img = setup
+    q = QuantConfig()
+    wq = vgg16_quantize_weights(params, q)
+    low = jax.jit(lambda p, x, w: vgg16_apply(p, x, l2r=q, weights_q=w)
+                  ).lower(params, img, wq)
+    ops = _scoped_ops(low)
+    for name in [layer.name for layer in VGG16_CONV_LAYERS] + [
+            "fc6", "fc7", "fc8"]:
+        assert (name, "dot_general") in ops, name
