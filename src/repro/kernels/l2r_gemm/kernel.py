@@ -34,15 +34,22 @@ stacking** schedule.  Hardware mapping:
     bit-identical to the pair loop (validated against
     ``core/online.py:tail_bound`` semantics in the tests).
 
-VMEM budget, stacked schedule, default (bm, bk, bn) = (128, 256, 128):
-  A block 32 KiB (int8) + B block 32 KiB + int32 acc 64 KiB = 128 KiB
-  (~256 KiB with double buffering) << 16 MiB/core — 3x leaner than the
-  pair-loop kernel, which additionally held 2 x D int32 plane workspaces
-  (256 KiB at radix 4).  M/N tiles are MXU-aligned (128); the int8 K
-  block is a multiple of 32 lanes.  HBM traffic: the stacked operands are
-  D x the int8 payload, but each block is read exactly once per output
-  tile — the same per-pair traffic the pair loop paid, now amortized over
-  MXU passes that are D x deeper on average.
+VMEM budget, stacked schedule: the default (bm, bk, bn) = (128, 256, 128)
+  holds an A block of 32 KiB (int8), a B block of 32 KiB and an int32
+  accumulator of 64 KiB (~256 KiB double-buffered) << 16 MiB/core — 3x
+  leaner than the pair-loop kernel, which additionally held 2 x D int32
+  plane workspaces (256 KiB at radix 4).  The fused conv chooses its tiles
+  from each tap GEMM's shape instead (:func:`stacked_tiles`): the whole
+  plane chunk as ``bk``, the padded output width as ``bn`` (each capped at
+  512), and the largest row tile dividing the padded rows whose blocks fit
+  the scoped VMEM, so a large feature map walks a few thousand grid steps
+  rather than hundreds of thousands of 128-row ones.  A kernel whose
+  blocks (:func:`stacked_vmem_bytes`) take over half the scoped VMEM asks
+  Mosaic for twice their size.  M/N tiles are MXU-aligned (multiples of
+  128); the int8 K block is a multiple of 32 lanes.  HBM traffic: the
+  stacked operands are D x the int8 payload, but each block is read
+  exactly once per output tile — the same per-pair traffic the pair loop
+  paid, now amortized over MXU passes that are D x deeper on average.
 
 Backend selection (jnp / pallas-interpret / pallas-tpu) lives in ops.py.
 """
@@ -64,7 +71,20 @@ from repro.core.quant import stack_planes_lhs, stack_planes_rhs
 __all__ = ["l2r_gemm_pallas", "l2r_gemm_pallas_stacked",
            "l2r_gemm_pallas_stacked_planes", "l2r_gemm_pallas_streaming",
            "l2r_gemm_pallas_streaming_planes", "stacked_schedule",
-           "streaming_schedule"]
+           "stacked_tiles", "stacked_vmem_bytes", "streaming_schedule"]
+
+# A TPU v5e core's VMEM: the scoped default a kernel gets without asking,
+# and all of it, the most a kernel may ask for.
+SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+VMEM_BYTES = 128 * 1024 * 1024
+# stacked_tiles keeps the blocks within the scoped default; the kernel
+# then asks for twice their size, leaving the compiler room for the dot's
+# int32 result and its own scratch.
+TILE_VMEM_BUDGET = SCOPED_VMEM_BYTES
+# Caps on the chosen tiles.  Rows: on a v5e, a conv1 tap at bm 8192 runs
+# within 2% of bm 12544 and 11% under bm 4096 (sweep in PERF.md §5).
+TILE_BM_CAP = 8192
+TILE_KN_CAP = 512
 
 
 # --------------------------------------------------------------- pair loop
@@ -186,6 +206,38 @@ def stacked_schedule(
     return (np.asarray(a_blocks, np.int32), np.asarray(b_blocks, np.int32))
 
 
+def stacked_vmem_bytes(bm: int, bk: int, bn: int) -> int:
+    """VMEM the stacked kernel's blocks take: the double-buffered int8 A
+    (bm, bk) and B (bk, bn) operand blocks, the double-buffered int32
+    (bm, bn) output block and the int32 accumulator."""
+    return 2 * (bm * bk + bk * bn) + 3 * bm * bn * 4
+
+
+def _largest_tile(dim: int, cap: int, fits=lambda t: True) -> int:
+    """Largest multiple of 128 that divides ``dim``, is at most ``cap``
+    and ``fits``; 128 where none larger does."""
+    return max((t for t in range(128, min(dim, cap) + 1, 128)
+                if dim % t == 0 and fits(t)), default=128)
+
+
+def stacked_tiles(m: int, k: int, n: int) -> tuple[int, int, int]:
+    """(bm, bk, bn) of the stacked kernel for an (m, D*k) x (D*k, n) GEMM.
+
+    ``m``, ``k`` (one plane chunk) and ``n`` are padded to multiples of
+    128.  ``bk`` and ``bn`` take the whole chunk and the whole width where
+    they fit the cap, so a tap walks one k-block per plane pair; ``bm`` is
+    the largest row tile that divides ``m`` (no row is padded past 128)
+    and keeps :func:`stacked_vmem_bytes` within the VMEM budget.  A pure
+    function of shape: every tile divides its dimension.
+    """
+    assert m % 128 == 0 and k % 128 == 0 and n % 128 == 0, (m, k, n)
+    bk = _largest_tile(k, TILE_KN_CAP)
+    bn = _largest_tile(n, TILE_KN_CAP)
+    bm = _largest_tile(m, TILE_BM_CAP, lambda t: stacked_vmem_bytes(
+        t, bk, bn) <= TILE_VMEM_BUDGET)
+    return bm, bk, bn
+
+
 def _l2r_stacked_kernel(a_idx_ref, b_idx_ref, a_ref, b_ref, o_ref, acc_ref,
                         *, t_steps: int):
     """One (bm, bn) output tile; grid = (M/bm, N/bn, T), schedule innermost.
@@ -269,10 +321,15 @@ def l2r_gemm_pallas_stacked_planes(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, t, ai, bi: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
     )
+    # blocks over half the scoped default ask for twice their size
+    vmem = 2 * stacked_vmem_bytes(bm, bk, bn)
+    params = (pltpu.CompilerParams(vmem_limit_bytes=min(vmem, VMEM_BYTES))
+              if vmem > SCOPED_VMEM_BYTES else None)
     return pl.pallas_call(
         functools.partial(_l2r_stacked_kernel, t_steps=t_steps),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
+        compiler_params=params,
         interpret=interpret,
         name=f"l2r_gemm_pallas_stacked_planes_{name}" if name else None,
     )(jnp.asarray(a_idx), jnp.asarray(b_idx), a_stack, b_rev)

@@ -71,7 +71,7 @@ from repro.core.quant import (PlaneOperands, QuantConfig, QuantizedWeights,
 from .kernel import (l2r_gemm_pallas, l2r_gemm_pallas_stacked,
                      l2r_gemm_pallas_stacked_planes,
                      l2r_gemm_pallas_streaming,
-                     l2r_gemm_pallas_streaming_planes)
+                     l2r_gemm_pallas_streaming_planes, stacked_tiles)
 from .ref import l2r_gemm_ref
 
 __all__ = ["l2r_gemm", "l2r_gemm_progressive", "l2r_attn_scores",
@@ -687,26 +687,28 @@ def _l2r_conv2d_int(
     # layout — each tap view of the stacked map feeds the pre-stacked
     # kernel entry directly (channels-last stacking commutes with the
     # spatial tap slicing), instead of re-extracting planes per tap.
-    # Per-tap K is only cin: shrink the contraction block to the smallest
-    # 128-lane multiple so shallow layers (cin=3) don't pad 9 taps to 256.
-    bk = min(256, -(-cin // 128) * 128)
-    ckp = cin + (-cin) % bk
+    # Every tap is the same (B*OH*OW, D*cin) x (D*cin, cout) GEMM: pad
+    # rows, the per-plane K chunk and cout to 128-multiples only, and take
+    # the tiles from that shape (kernel.py:stacked_tiles) so a large map
+    # walks few, large grid steps.
+    m0 = bsz * oh * ow
+    mp, ckp, coutp = (-(-v // 128) * 128 for v in (m0, cin, cout))
     xsp = stack_planes_lhs(xp, n_bits, log2_radix)  # (B, H', W', D*cin)
+    bm, bk, bn = stacked_tiles(mp, ckp, coutp)
     wrev = _conv_wrev(w_in, n_bits, log2_radix, shifted=True)
     wrev = jnp.pad(wrev.reshape(kh, kw, d, cin, cout),
                    ((0, 0), (0, 0), (0, 0), (0, ckp - cin),
-                    (0, (-cout) % 128)))
-    wrev = wrev.reshape(kh, kw, d * ckp, -1)
+                    (0, coutp - cout)))
+    wrev = wrev.reshape(kh, kw, d * ckp, coutp)
     interpret = backend == "pallas-interpret"
     for dy in range(kh):
         for dx in range(kw):
             a = _tap_view(xsp, dy, dx, oh, ow, stride, dilation)
-            a2 = a.reshape(-1, d, cin)
-            m0 = a2.shape[0]
-            a2 = jnp.pad(a2, (((0, (-m0) % 128), (0, 0), (0, ckp - cin))))
+            a2 = jnp.pad(a.reshape(m0, d, cin),
+                         ((0, mp - m0), (0, 0), (0, ckp - cin)))
             t = l2r_gemm_pallas_stacked_planes(
-                a2.reshape(a2.shape[0], -1), wrev[dy, dx], n_bits,
-                log2_radix, levels, 128, bk, 128, interpret=interpret,
+                a2.reshape(mp, d * ckp), wrev[dy, dx], n_bits,
+                log2_radix, levels, bm, bk, bn, interpret=interpret,
                 name=name)
             acc = acc + t[:m0, :cout].reshape(bsz, oh, ow, cout)
     return acc

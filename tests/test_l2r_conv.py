@@ -14,6 +14,8 @@ import pytest
 from repro.core.l2r_gemm import l2r_matmul_int
 from repro.core.quant import QuantConfig, QuantizedWeights, quantize_weights
 from repro.kernels.l2r_gemm import l2r_conv2d, l2r_conv2d_progressive
+from repro.kernels.l2r_gemm.kernel import (TILE_VMEM_BUDGET, stacked_tiles,
+                                           stacked_vmem_bytes)
 from repro.kernels.l2r_gemm.ops import (_l2r_conv2d_int,
                                         _l2r_conv2d_progressive_int)
 
@@ -58,6 +60,62 @@ def test_fused_conv_backends_agree():
     out_jnp = np.asarray(_l2r_conv2d_int(xq, wq, 8, 2, None, "jnp"))
     out_pl = np.asarray(_l2r_conv2d_int(xq, wq, 8, 2, None, "pallas-interpret"))
     np.testing.assert_array_equal(out_pl, out_jnp)
+
+
+def _pad128(v):
+    return -(-v // 128) * 128
+
+
+@pytest.mark.parametrize("x_shape,cin,cout,levels,tiles", [
+    ((1, 16, 16), 200, 192, None, (256, 256, 256)),  # bk = a whole chunk
+    ((2, 16, 16), 3, 256, None, (512, 128, 256)),
+    ((2, 12, 12), 130, 64, 5, (384, 256, 128)),      # truncated walk
+], ids=["bk_whole_chunk", "bm512_bn256", "bm384_levels5"])
+def test_fused_conv_shape_chosen_tiles_bit_identical(x_shape, cin, cout,
+                                                     levels, tiles):
+    """The Pallas conv at tiles wider than 128 in every dimension equals
+    the jnp backend and quantized im2col, bit for bit."""
+    bsz, h, w_ = x_shape
+    assert stacked_tiles(_pad128(bsz * h * w_), _pad128(cin),
+                         _pad128(cout)) == tiles
+    rng = np.random.default_rng(cin + cout)
+    xq = jnp.asarray(rng.integers(-128, 128, (*x_shape, cin), dtype=np.int8))
+    wq = jnp.asarray(rng.integers(-128, 128, (3, 3, cin, cout),
+                                  dtype=np.int8))
+    out_pl = np.asarray(_l2r_conv2d_int(xq, wq, 8, 2, levels,
+                                        "pallas-interpret"))
+    out_jnp = np.asarray(_l2r_conv2d_int(xq, wq, 8, 2, levels, "jnp"))
+    np.testing.assert_array_equal(out_pl, out_jnp)
+    np.testing.assert_array_equal(out_pl, _im2col_int(xq, wq, levels))
+
+
+def _vgg16_taps(batch):
+    from repro.core.cycle_model import VGG16_CONV_LAYERS
+    return [(_pad128(batch * l.R * l.C), _pad128(l.N), _pad128(l.M))
+            for l in VGG16_CONV_LAYERS]
+
+
+@pytest.mark.parametrize("m,k,n", _vgg16_taps(8) + _vgg16_taps(1) + [
+    (128, 128, 128), (1664, 640, 1536), (384, 384, 384), (128 * 97, 128, 64 * 128),
+])
+def test_stacked_tiles_divide_and_fit(m, k, n):
+    """Every tile is a multiple of 128 dividing its padded dimension, and
+    the blocks fit the VMEM budget."""
+    tiles = stacked_tiles(m, k, n)
+    for t, dim in zip(tiles, (m, k, n)):
+        assert t % 128 == 0 and dim % t == 0, (tiles, (m, k, n))
+    assert stacked_vmem_bytes(*tiles) <= TILE_VMEM_BUDGET
+
+
+def test_stacked_tiles_vgg16_grid_steps():
+    """VGG-16 at batch 8 walks under 100,000 grid steps over its 13 convs
+    (1,484,352 at the fixed (128, <=256, 128) tiles): rows x columns x
+    16 plane pairs x k-blocks x 9 taps."""
+    steps = 0
+    for m, k, n in _vgg16_taps(8):
+        bm, bk, bn = stacked_tiles(m, k, n)
+        steps += (m // bm) * (n // bn) * 16 * (k // bk) * 9
+    assert steps < 100_000, steps
 
 
 def test_fused_conv_w8a8_close_to_lax_conv():

@@ -16,11 +16,13 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.cycle_model import VGG16_CONV_LAYERS
 from repro.core.quant import plane_count
 from repro.kernels.flash_attention import flash_attention_l2r_pallas
 from repro.kernels.l2r_gemm import (l2r_gemm_pallas,
                                     l2r_gemm_pallas_stacked_planes,
-                                    l2r_gemm_pallas_streaming_planes)
+                                    l2r_gemm_pallas_streaming_planes,
+                                    stacked_tiles)
 
 D = plane_count(8, 2)  # radix-4 digit planes of an 8-bit operand
 
@@ -56,21 +58,20 @@ def _compile(fn, one_chip, *shapes):
 
 def _conv_tap(m: int, cin: int, cout: int):
     """One tap of the fused conv at layer width: the (M, D*Kp) x
-    (D*Kp, N) pre-stacked GEMM with the conv's block choice
+    (D*Kp, N) pre-stacked GEMM at the tiles the conv chooses for it
     (ops.py:_l2r_conv2d_int)."""
-    bk = min(256, -(-cin // 128) * 128)
-    kp = cin + (-cin) % bk
-    mp, np_ = m + (-m) % 128, cout + (-cout) % 128
+    mp, kp, np_ = (v + (-v) % 128 for v in (m, cin, cout))
+    bm, bk, bn = stacked_tiles(mp, kp, np_)
     fn = lambda a, b: l2r_gemm_pallas_stacked_planes(a, b, 8, 2, None,
-                                                     128, bk, 128)
+                                                     bm, bk, bn)
     return fn, ((mp, D * kp), jnp.int8), ((D * kp, np_), jnp.int8)
 
 
-@pytest.mark.parametrize("layer,m,cin,cout", [
-    ("conv1_1", 224 * 224, 3, 64),   # cin 3 pads to one 128-lane block
-    ("conv4_2", 28 * 28, 512, 512),
-], ids=["vgg_conv1_1", "vgg_conv4_2"])
-def test_stacked_kernel_vgg_widths(one_chip, layer, m, cin, cout):
+@pytest.mark.parametrize(
+    "m,cin,cout", [(8 * l.R * l.C, l.N, l.M) for l in VGG16_CONV_LAYERS],
+    ids=[f"vgg_{l.name}" for l in VGG16_CONV_LAYERS])
+def test_stacked_kernel_vgg_widths(one_chip, m, cin, cout):
+    """Every VGG-16 conv tap at batch 8, 224x224."""
     fn, a, b = _conv_tap(m, cin, cout)
     _compile(fn, one_chip, a, b)
 
