@@ -320,8 +320,11 @@ def audit_registry() -> list[dict]:
     otherwise (``declared`` records which) — at the arch's real
     contraction lengths: the head walk over ``d_model``
     (serve.engine quantizes head weights with ``k = d_model``) and the
-    attention score walk over ``head_dim``.  Returns one report row per
-    (arch, site).
+    attention score walk over ``head_dim``; for an MoE arch also the
+    FFN down projections, whose contractions are the widest after the
+    head's: a routed expert's (``moe_d_ff``), the shared experts'
+    (``moe_d_ff * n_shared_experts``) and a dense layer's
+    (``dense_d_ff``).  Returns one report row per (arch, site).
     """
     from repro.configs import registry  # deferred: configs pull in models
 
@@ -332,6 +335,15 @@ def audit_registry() -> list[dict]:
             ("head", cfg.l2r, cfg.l2r_levels, cfg.d_model),
             ("attention", cfg.attn_l2r, cfg.attn_levels, cfg.head_dim),
         ]
+        if cfg.n_experts:
+            f = cfg.moe_d_ff or cfg.d_ff
+            sites.append(("expert_down", cfg.l2r, cfg.l2r_levels, f))
+            if cfg.n_shared_experts:
+                sites.append(("shared_down", cfg.l2r, cfg.l2r_levels,
+                              f * cfg.n_shared_experts))
+            if cfg.dense_d_ff and "mlp" in cfg.ffn_kinds():
+                sites.append(("dense_down", cfg.l2r, cfg.l2r_levels,
+                              cfg.dense_d_ff))
         for site, qc, levels, k in sites:
             declared = qc is not None
             if qc is None:

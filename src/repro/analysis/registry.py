@@ -114,6 +114,22 @@ def _gemm_entry(schedule: str, backend: str, early_exit: bool = False,
                                    levels=levels, mode=mode))
 
 
+def _experts_entry():
+    """The held experts' GEMM: the stacked schedule vmapped over an
+    expert axis, one weight per expert (models/moe.py:_expert_ffn)."""
+    def build():
+        from repro.kernels.l2r_gemm.ops import l2r_gemm
+        ops = [_gemm_operands(seed=s) for s in (3, 4)]
+        aq, bq = (np.stack(x) for x in zip(*ops))
+        fn = jax.vmap(functools.partial(l2r_gemm, n_bits=8, log2_radix=2,
+                                        backend="jnp", name="experts"))
+        return fn, (aq, bq)
+
+    return ExactEntry(
+        name="gemm/stacked-experts/jnp", build=build, tags=("gemm", "jnp"),
+        contract=ExactnessContract(n_bits=8, log2_radix=2, k=24))
+
+
 def _attn_entry(kind: str):
     def build():
         from repro.core import l2r_attention as la
@@ -281,6 +297,7 @@ def default_entries() -> list[ExactEntry]:
         _gemm_entry("streaming", "jnp"),
         _gemm_entry("streaming", "jnp", early_exit=True),
         _gemm_entry("stacked", "jnp", levels=3),
+        _experts_entry(),
         _gemm_entry("stacked", "pallas-interpret", mode="kernel-int"),
         _gemm_entry("streaming", "pallas-interpret", mode="kernel-int"),
         _attn_entry("stacked"),

@@ -1,6 +1,6 @@
 """Architecture registry: --arch <id> -> config, shapes, input specs.
 
-The 10 assigned architectures x 4 shapes = 40 cells.  `long_500k`
+The 11 architectures x 4 shapes = 44 cells.  `long_500k`
 requires sub-quadratic attention: it runs for the SSM/hybrid/mostly-local
 archs and is a documented skip for the pure-full-attention ones
 (DESIGN.md §4; EXPERIMENTS.md §Dry-run lists each skip).
@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from repro.models.config import ModelConfig
 
-from . import (deepseek_moe_16b, gemma3_27b, granite_8b,
+from . import (deepseek_moe_16b, deepseek_v2_lite, gemma3_27b, granite_8b,
                llama4_maverick_400b_a17b, mamba2_130m, phi3_medium_14b,
                qwen2_vl_7b, recurrentgemma_2b, smollm_135m, whisper_base)
 
@@ -31,6 +31,7 @@ _MODULES = {
     "mamba2-130m": mamba2_130m,
     "llama4-maverick-400b-a17b": llama4_maverick_400b_a17b,
     "deepseek-moe-16b": deepseek_moe_16b,
+    "deepseek-v2-lite": deepseek_v2_lite,
     "whisper-base": whisper_base,
     "qwen2-vl-7b": qwen2_vl_7b,
 }

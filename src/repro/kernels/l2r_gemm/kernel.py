@@ -68,7 +68,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.online import msdf_level_slices, msdf_pairs
 from repro.core.quant import stack_planes_lhs, stack_planes_rhs
 
-__all__ = ["l2r_gemm_pallas", "l2r_gemm_pallas_stacked",
+__all__ = ["DENSE_BM", "l2r_gemm_pallas", "l2r_gemm_pallas_stacked",
            "l2r_gemm_pallas_stacked_planes", "l2r_gemm_pallas_streaming",
            "l2r_gemm_pallas_streaming_planes", "stacked_schedule",
            "stacked_tiles", "stacked_vmem_bytes", "streaming_schedule"]
@@ -85,6 +85,10 @@ TILE_VMEM_BUDGET = SCOPED_VMEM_BYTES
 # within 2% of bm 12544 and 11% under bm 4096 (sweep in PERF.md §5).
 TILE_BM_CAP = 8192
 TILE_KN_CAP = 512
+# The dense path's row tile (ops.l2r_gemm's default ``bm``): a GEMM's
+# rows are padded up to a multiple of it, so a caller sizing a row
+# buffer (models/moe.py) rounds to it.
+DENSE_BM = 128
 
 
 # --------------------------------------------------------------- pair loop

@@ -68,7 +68,7 @@ from repro.core.quant import (PlaneOperands, QuantConfig, QuantizedWeights,
                               plane_count, quantize, quantize_weights,
                               stack_planes_lhs, stack_planes_rhs)
 
-from .kernel import (l2r_gemm_pallas, l2r_gemm_pallas_stacked,
+from .kernel import (DENSE_BM, l2r_gemm_pallas, l2r_gemm_pallas_stacked,
                      l2r_gemm_pallas_stacked_planes,
                      l2r_gemm_pallas_streaming,
                      l2r_gemm_pallas_streaming_planes, stacked_tiles)
@@ -296,7 +296,7 @@ def l2r_gemm(
     n_bits: int = 8,
     log2_radix: int = 2,
     levels: int | None = None,
-    bm: int = 128,
+    bm: int = DENSE_BM,
     bk: int = 256,
     bn: int = 128,
     schedule: str = "stacked",

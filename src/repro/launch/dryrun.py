@@ -65,11 +65,19 @@ def lower_cell(arch: str, shape: str, multi_pod: bool,
 
     Hillclimb switches (all default off -> paper-faithful baseline):
       score_bf16 — bf16 attention score blocks (f32 stats);
-      moe_hints  — interior sharding hints on the MoE dispatch path;
+      moe_hints  — interior sharding hints on the MoE dispatch path
+                   (training's moe_apply and serving's moe_serve);
+      moe_dp_local — DP-local capacity dispatch, training cells only:
+                   serving's MoE is dropless and refuses it;
       wq         — int8-stored weights (W8A8 L2R serving arithmetic).
     """
     import dataclasses as _dc
 
+    if moe_dp_local and SHAPES[shape].kind != "train":
+        raise ValueError(
+            f"--moe-dp-local drops tokens past a capacity, which serving "
+            f"does not (models/moe.py:moe_serve); {shape} is a "
+            f"{SHAPES[shape].kind} cell")
     mesh = make_production_mesh(multi_pod=multi_pod)
     cfg = get_config(arch)
     if l2r:
@@ -249,7 +257,8 @@ def main():
     ap.add_argument("--kv-seq-shard", action="store_true",
                     help="shard KV caches on the sequence dim (hillclimb)")
     ap.add_argument("--moe-dp-local", action="store_true",
-                    help="DP-local-capacity MoE dispatch (hillclimb)")
+                    help="DP-local-capacity MoE dispatch, training "
+                         "cells only (hillclimb)")
     ap.add_argument("--head-shard", action="store_true",
                     help="shard attention on the KV-head dim (hillclimb)")
     args = ap.parse_args()

@@ -108,6 +108,7 @@ def dense(
     w,
     l2r: QuantConfig | None = None,
     l2r_levels: int | None = None,
+    name: str | None = None,
 ) -> jax.Array:
     """x @ w with optional L2R digit-plane arithmetic (the paper's unit).
 
@@ -127,6 +128,9 @@ def dense(
     decode; the integer product is exactly what the L2R composite IPU
     computes digit-serially (bit equality proven in
     tests/test_kernel_l2r_gemm.py).
+
+    ``name`` tags the L2R kernel in device traces
+    (kernels/l2r_gemm/ops.py:l2r_matmul_f); results never depend on it.
     """
     if isinstance(w, QuantizedWeights):
         trail = w.q.shape[1:]
@@ -142,7 +146,8 @@ def dense(
                     planes, stack=planes.stack.reshape(
                         planes.stack.shape[0], -1), axis=-2)
             out = l2r_matmul_f(x, None, l2r, l2r_levels,
-                               w_q=QuantizedWeights(wq, ws, planes))
+                               w_q=QuantizedWeights(wq, ws, planes),
+                               name=name)
             return out.reshape(*x.shape[:-1], *trail)
         lead = x.shape[:-1]
         x2 = x.reshape(-1, x.shape[-1])
@@ -172,44 +177,57 @@ def dense(
         out = out.astype(jnp.float32) * xs * scale.reshape(()).astype(jnp.float32)
         return out.astype(x.dtype).reshape(*lead, *trail)
     if w.ndim > 2:
-        out = dense(x, w.reshape(w.shape[0], -1), l2r, l2r_levels)
+        out = dense(x, w.reshape(w.shape[0], -1), l2r, l2r_levels, name)
         return out.reshape(*x.shape[:-1], *w.shape[1:])
     if l2r is not None:
         # production L2R path: the backend-dispatched level-stacked kernel
         # (kernels/l2r_gemm), not the pure-jnp core pair loop
-        return l2r_matmul_f(x, w, l2r, l2r_levels)
+        return l2r_matmul_f(x, w, l2r, l2r_levels, name=name)
     return l2r_dense(x, w, l2r, l2r_levels)
 
 
 def _quantizable(p: Param) -> bool:
     """Matmul weights eligible for int8 storage: 2D+ normal-init params
-    that are not embedding/vocab tables (lookup + tied logits stay f32).
-    Routed-expert stacks are excluded for now (their einsum path takes
-    raw arrays; per-expert int8 goes through kernels/l2r_gemm instead)."""
+    that are not embedding/vocab tables (lookup + tied logits stay f32),
+    not MoE routers (routing runs in float32), and not MLA's latent
+    up-projection (its absorbed decode reorders the product, which int8
+    activations would not commute with).  Routed-expert stacks
+    (:func:`_expert_stack`) are eligible and keep a scale per expert."""
     return (p.init == "normal" and len(p.shape) >= 2
-            and "vocab" not in p.axes and "experts" not in p.axes)
+            and not {"vocab", "router", "latent"} & set(p.axes))
+
+
+def _expert_stack(p: Param) -> bool:
+    """A routed-expert weight stack ((layers,) experts, K, ..., N)."""
+    return p.init == "normal" and "experts" in p.axes and len(p.shape) >= 3
+
+
+def _scale_lead(p: Param) -> int:
+    """How many leading axes of an eligible weight keep their own
+    {"q", "scale"} scale: the stacked layer axis, and an expert stack's
+    expert axis."""
+    if _expert_stack(p):
+        return p.axes.index("experts") + 1
+    return 1 if p.axes and p.axes[0] == "layers" else 0
 
 
 def quantize_desc(desc_tree):
     """Descriptor transform: eligible Param -> {"q": int8, "scale": f32}.
 
-    One scale per (stacked layer x) tensor; dense() dispatches on the
-    record.  This is the serving-time storage format of the L2R pipeline:
-    the Pallas kernel consumes exactly these int8 operands and streams
-    their digit planes MSDF in VMEM.
+    One scale per (stacked layer x) (expert x) tensor; dense() dispatches
+    on the record.  This is the serving-time storage format of the L2R
+    pipeline: the Pallas kernel consumes exactly these int8 operands and
+    streams their digit planes MSDF in VMEM.
     """
     def f(p: Param):
         if not _quantizable(p):
             return p
-        stacked = p.axes and p.axes[0] == "layers"
-        sshape = (p.shape[0],) + (1,) * (len(p.shape) - 1) if stacked \
-            else (1,) * len(p.shape)
-        saxes = ("layers",) + (None,) * (len(p.shape) - 1) if stacked \
-            else (None,) * len(p.shape)
+        n, rest = _scale_lead(p), len(p.shape) - _scale_lead(p)
         return {
             "q": Param(p.shape, p.axes, init=p.init, scale=p.scale,
                        dtype=jnp.int8),
-            "scale": Param(sshape, saxes, init="ones"),
+            "scale": Param(p.shape[:n] + (1,) * rest,
+                           p.axes[:n] + (None,) * rest, init="ones"),
         }
     return jax.tree.map(f, desc_tree, is_leaf=_is_param)
 
@@ -222,12 +240,8 @@ def quantize_params(desc_tree, params):
         if not _quantizable(p):
             return w
         wf = w.astype(jnp.float32)
-        stacked = p.axes and p.axes[0] == "layers"
-        if stacked:  # one scale per stacked layer
-            amax = jnp.max(jnp.abs(wf), axis=tuple(range(1, wf.ndim)),
-                           keepdims=True)
-        else:
-            amax = jnp.max(jnp.abs(wf)).reshape((1,) * wf.ndim)
+        amax = jnp.max(jnp.abs(wf), axis=tuple(range(_scale_lead(p), wf.ndim)),
+                       keepdims=True)
         scale = jnp.maximum(amax, 1e-30) / 127.0
         q = jnp.clip(jnp.round(wf / scale), -127, 127)
         return {"q": q.astype(jnp.int8), "scale": scale}
@@ -240,8 +254,9 @@ def quantize_tree(desc_tree, params, cfg: QuantConfig = QuantConfig(),
 
     The load-time L2R weight cache for full model trees: every eligible
     matmul weight (same eligibility as quantize_desc) is quantized ONCE,
-    per out-channel (and per stacked layer), so serving traces carry no
-    weight quantization ops.  dense() consumes the records directly.
+    per out-channel (and per stacked layer and expert), so serving traces
+    carry no weight quantization ops.  dense() consumes the records
+    directly.
 
     ``prestack=True`` additionally caches each weight's reversed RHS
     digit-plane stack (core/quant.py:PlaneOperands, contraction axis 0 —
@@ -249,8 +264,15 @@ def quantize_tree(desc_tree, params, cfg: QuantConfig = QuantConfig(),
     forward scan strips) so the serving traces carry no weight plane
     extraction either: D x the int8 weight bytes buys
     extract-once-per-process operands.
+
+    Routed-expert stacks quantize per (layer,) expert and out-channel,
+    their contraction axis the one after the expert axis.
     """
     def f(p: Param, w):
+        if _expert_stack(p):
+            e = p.axes.index("experts")
+            return quantize_weights(w, cfg, channel_axes=(*range(e + 1), -1),
+                                    prestack=prestack, plane_axis=e + 1)
         if not _quantizable(p):
             return w
         stacked = p.axes and p.axes[0] == "layers"

@@ -23,7 +23,7 @@ class ModelConfig:
     head_dim: int | None = None  # None -> d_model // n_heads
 
     # --- mixer pattern (cycled over layers) ---
-    layer_pattern: Tuple[str, ...] = ("global",)  # global|local|rec|ssd
+    layer_pattern: Tuple[str, ...] = ("global",)  # global|local|rec|ssd|mla
     window: int = 4096  # local attention window
     rope_theta: float = 10_000.0
     rope_mode: str = "standard"  # standard | mrope | none
@@ -36,6 +36,20 @@ class ModelConfig:
     attn_head_shard: bool = False  # shard attention math on the KV-head
     # dim (uneven counts padded by GSPMD) — §Perf hillclimb A
 
+    # --- multi-head latent attention (the ``mla`` mixer, DeepSeek-V2) ---
+    kv_lora_rank: int = 0  # latent width of the compressed KV (c_kv)
+    qk_nope_dim: int = 0  # per-head query/key width without rope
+    qk_rope_dim: int = 0  # per-head rope width (the shared k_pe)
+    v_head_dim: int = 0  # per-head value width
+
+    # --- YaRN rope scaling (mla mixer; factor 1 is plain rope) ---
+    yarn_factor: float = 1.0
+    yarn_original_len: int = 4096  # original_max_position_embeddings
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale_all_dim: float = 0.0  # softmax scale's mscale; the
+    # published mscale must equal it (cos/sin then keep a factor of 1)
+
     # --- ffn ---
     ffn_kind: str = "swiglu"  # swiglu | geglu | gelu
     ffn_pattern: Tuple[str, ...] = ("mlp",)  # mlp | moe (cycled)
@@ -47,6 +61,11 @@ class ModelConfig:
     experts_per_token: int = 1
     n_shared_experts: int = 0
     moe_d_ff: int = 0  # per-expert hidden; d_ff is the dense-layer hidden
+    moe_norm_topk: bool = True  # renormalise the top-k gates to sum 1;
+    # False keeps the raw softmax probabilities (DeepSeek-V2)
+    n_experts_held: int = 0  # experts whose weights this chip holds, a
+    # share of the router's n_experts (expert parallelism); 0 -> all
+    expert_offset: int = 0  # global index of the first held expert
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
     moe_dp_local: bool = False  # DP-local capacity dispatch: shard-local
@@ -104,6 +123,10 @@ class ModelConfig:
     @property
     def qkv_dim(self) -> int:
         return self.n_heads * self.head_dim
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
 
     def mixer_kinds(self) -> Tuple[str, ...]:
         p = self.layer_pattern

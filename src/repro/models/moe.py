@@ -1,24 +1,56 @@
 """Mixture-of-Experts FFN: top-k routing with capacity, scatter dispatch,
 shared experts (DeepSeekMoE) and top-1 routed + shared (Llama-4 style).
 
-Dispatch is scatter/gather based — token t's i-th choice of expert e gets
-slot p = (number of earlier assignments to e); assignments beyond the
-static capacity C are dropped (standard capacity dropping).  This avoids
-the (tokens, experts, capacity) one-hot einsum blow-up and maps onto an
-all-to-all when experts are sharded over the "model" mesh axis.
+Training dispatch (:func:`moe_apply`) is scatter/gather based — token
+t's i-th choice of expert e gets slot p = (number of earlier assignments
+to e); assignments beyond the static capacity C are dropped (standard
+capacity dropping).  This avoids the (tokens, experts, capacity) one-hot
+einsum blow-up and maps onto an all-to-all when experts are sharded over
+the "model" mesh axis.
+
+Serving (:func:`moe_serve`) never drops: capacity dropping would make a
+request's output depend on the requests batched with it.  Each held
+expert computes the rows routed to it in a buffer of
+``capacity_factor`` times their expected count; a step in which one
+expert is routed more computes further rounds of such buffers until
+every routed row has been computed.
+
+**Held experts.** The router always scores all ``n_experts`` and picks
+its top-k over all of them.  A layer told that it holds
+``n_experts_held`` experts from ``expert_offset`` on (one chip's share
+under expert parallelism) has only those experts' weights and adds only
+their part of the result; the choices of experts held elsewhere add
+nothing here.  On one chip there is no exchange.
+
+Routing runs in float32 (the router's logits decide discrete choices).
+Gates are the softmax probabilities of the chosen experts, renormalised
+to sum 1 when ``moe_norm_topk``, otherwise kept raw (DeepSeek-V2's gate
+with its published ``routed_scaling_factor`` of 1).  Named scopes:
+``moe_route``, ``moe_experts`` and ``moe_shared``; the expert GEMMs'
+L2R kernels are named ``l2r_gemm_pallas_stacked_planes_experts``.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 
+from repro.core.quant import QuantizedWeights
+from repro.kernels.l2r_gemm.kernel import DENSE_BM
+
 from .common import Param, dense
 from .config import ModelConfig
 
-__all__ = ["moe_build", "moe_apply", "moe_capacity"]
+__all__ = ["moe_build", "moe_apply", "moe_serve", "moe_capacity",
+           "moe_serve_capacity", "MOE_COUNTS"]
+
+# the serving counters moe_serve returns, in order (int32 (3,)): rows
+# routed to held experts, expert rows computed (padding included), and
+# the most rows one held expert was routed
+MOE_COUNTS = ("rows_routed", "rows_computed", "rows_max_expert")
 
 
 def moe_capacity(cfg: ModelConfig, n_tokens: int) -> int:
@@ -28,10 +60,10 @@ def moe_capacity(cfg: ModelConfig, n_tokens: int) -> int:
 
 
 def moe_build(cfg: ModelConfig) -> dict:
-    e, d, f = cfg.n_experts, cfg.d_model, cfg.moe_d_ff or cfg.d_ff
+    e, d, f = cfg.experts_held, cfg.d_model, cfg.moe_d_ff or cfg.d_ff
     glu = cfg.ffn_kind in ("swiglu", "geglu")
     params = {
-        "router": Param((d, e), ("embed", None), scale=0.02),
+        "router": Param((d, cfg.n_experts), ("embed", "router"), scale=0.02),
         "wi": Param((e, d, 2, f) if glu else (e, d, f),
                     ("experts", "embed", None, "ffn") if glu else ("experts", "embed", "ffn")),
         "wo": Param((e, f, d), ("experts", "ffn", "embed")),
@@ -49,20 +81,21 @@ def moe_build(cfg: ModelConfig) -> dict:
 def _expert_ffn(cfg: ModelConfig, wi, wo, xb: jax.Array) -> jax.Array:
     """xb: (E, C, d) -> (E, C, d); per-expert GLU/GELU FFN.
 
-    With the L2R switch on, expert matmuls run through the **backend
-    dispatcher** (kernels/l2r_gemm/ops.py:l2r_matmul_f) vmapped over
-    experts: they pick up the level-stacked schedule, the guarded f32
-    BLAS fast path, and the ``REPRO_L2R_BACKEND`` override exactly like
-    the dense stack — per-expert activation/weight scales come from the
-    quantization happening inside the vmapped call."""
+    With the L2R switch on, or with weights quantized per expert
+    (``prepare_params``' :class:`~repro.core.quant.QuantizedWeights`, or
+    the ``{"q", "scale"}`` records of ``quantize_desc``), the expert
+    matmuls run through :func:`~repro.models.common.dense` vmapped over
+    experts: the backend-dispatched level-stacked kernel, named
+    ``experts`` in device traces, with per-row activation scales and
+    per-expert weight scales."""
     glu = cfg.ffn_kind in ("swiglu", "geglu")
-    if cfg.l2r is not None:
-        from repro.kernels.l2r_gemm.ops import l2r_matmul_f
+    l2r = cfg.l2r is not None or isinstance(wi, (QuantizedWeights, dict))
 
-        wi2 = wi.reshape(wi.shape[0], wi.shape[1], -1)
-        h = jax.vmap(lambda xe, we: l2r_matmul_f(xe, we, cfg.l2r, cfg.l2r_levels))(
-            xb, wi2
-        ).reshape(xb.shape[0], xb.shape[1], *wi.shape[2:])
+    def mm(xs, ws):
+        return jax.vmap(lambda xe, we: dense(xe, we, cfg.l2r, cfg.l2r_levels,
+                                             name="experts"))(xs, ws)
+    if l2r:
+        h = mm(xb, wi)
     else:
         h = jnp.einsum("ecd,ed...f->ec...f", xb, wi.astype(xb.dtype))
     if glu:
@@ -71,13 +104,123 @@ def _expert_ffn(cfg: ModelConfig, wi, wo, xb: jax.Array) -> jax.Array:
         h = act * up
     else:
         h = jax.nn.gelu(h)
-    if cfg.l2r is not None:
-        from repro.kernels.l2r_gemm.ops import l2r_matmul_f
-
-        return jax.vmap(lambda he, we: l2r_matmul_f(he, we, cfg.l2r, cfg.l2r_levels))(
-            h, wo
-        )
+    if l2r:
+        return mm(h, wo)
     return jnp.einsum("ecf,efd->ecd", h, wo.astype(xb.dtype))
+
+
+def _route(cfg: ModelConfig, params: dict, xt: jax.Array):
+    """Float32 top-k routing of tokens xt (T, d) over all ``n_experts``.
+    Returns (probs (T, E), gates (T, k), local expert index (T, k),
+    held (T, k)): a choice is held where its expert is one of this
+    layer's ``experts_held`` from ``expert_offset``; ``local`` is its
+    index among them."""
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(xt.astype(jnp.float32),
+                         params["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, idx = jax.lax.top_k(probs, cfg.experts_per_token)
+        if cfg.moe_norm_topk:
+            gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+        local = idx - cfg.expert_offset
+        held = (local >= 0) & (local < cfg.experts_held)
+        return probs, gates, local, held
+
+
+def _shared_ffn(cfg: ModelConfig, params: dict, xt: jax.Array) -> jax.Array:
+    with jax.named_scope("moe_shared"):
+        glu = cfg.ffn_kind in ("swiglu", "geglu")
+        h = dense(xt, params["shared_wi"], cfg.l2r, cfg.l2r_levels)
+        if glu:
+            g_, u_ = h[..., 0, :], h[..., 1, :]
+            h = (jax.nn.silu(g_) if cfg.ffn_kind == "swiglu" else jax.nn.gelu(g_)) * u_
+        else:
+            h = jax.nn.gelu(h)
+        return dense(h, params["shared_wo"], cfg.l2r, cfg.l2r_levels)
+
+
+def moe_serve_capacity(cfg: ModelConfig, n_tokens: int) -> int:
+    """Rows per held expert in one round of serving's dispatch:
+    ``capacity_factor`` times the expected share of ``n_tokens``, rounded
+    up to the L2R kernel's row tile (fewer rows would be padded to it
+    anyway), at most ``n_tokens``."""
+    want = cfg.capacity_factor * n_tokens * cfg.experts_per_token \
+        / max(cfg.n_experts, 1)
+    return min(n_tokens, DENSE_BM * math.ceil(want / DENSE_BM))
+
+
+def moe_serve(cfg: ModelConfig, params: dict, x: jax.Array,
+              valid: jax.Array | None = None):
+    """Serving MoE on x (B, S, d), dropless.  Returns (out, counts) with
+    ``counts`` the int32 :data:`MOE_COUNTS` of this call.
+
+    Each held expert takes the rows routed to it, in token order, into a
+    buffer of :func:`moe_serve_capacity` rows.  Where one expert is
+    routed more rows than that, its rows beyond the buffer go through
+    further rounds of the same buffers (a ``while`` loop that runs only
+    then), so nothing is dropped and the buffers never grow.  Rows
+    are independent (per-row activation scales, exact integer GEMMs)
+    and a token sums its choices in one fixed order, so its output does
+    not depend on the round that computed it or on the rows beside it.
+    ``valid`` (B, S) marks the rows whose output is used; the others
+    (bucket padding, a packed prefill's dummy rows) are routed nowhere
+    and add only the shared experts.  Under a mesh the tokens stay
+    data-parallel and the expert buffers lie on the "model" axis, as in
+    :func:`moe_apply`."""
+    from repro.sharding.ctx import hint, hint_dp
+
+    b, s, d = x.shape
+    t, eh, k = b * s, cfg.experts_held, cfg.experts_per_token
+    cap = moe_serve_capacity(cfg, t)
+    xt = hint_dp(x.reshape(t, d))  # tokens stay DP-sharded through routing
+    _, gates, local, held = _route(cfg, params, xt)
+    if valid is not None:
+        held = held & valid.reshape(t, 1)
+    with jax.named_scope("moe_experts"):
+        expert = jnp.where(held, local, 0)  # (T, k)
+        onehot = jax.nn.one_hot(jnp.where(held, local, -1), eh,
+                                dtype=jnp.int32)  # (T, k, E_h)
+        per_expert = onehot.sum((0, 1))
+        # slot of each held choice among its expert's rows, in token order
+        before = jnp.cumsum(onehot.reshape(t * k, eh), 0).reshape(t, k, eh) \
+            - onehot
+        slot = jnp.take_along_axis(before, expert[..., None], -1)[..., 0]
+        rounds = (per_expert.max() + cap - 1) // cap
+        ffn = functools.partial(_expert_ffn, cfg, params["wi"], params["wo"])
+        # each choice's row, flat (T k, d): a scatter of these stays on
+        # the data axis of a mesh, where a broadcast (T, k, d) update did
+        # not (several times the temporaries in a 16x16 prefill dry run)
+        rows = hint_dp(xt[jnp.repeat(jnp.arange(t), k)])
+
+        def one_round(r):
+            """The held choices in slots [r cap, (r + 1) cap) of their
+            expert's rows, and their experts' outputs (T, k, d)."""
+            here = held & (slot // cap == r)
+            buf = jnp.zeros((eh, cap, d), x.dtype).at[
+                jnp.where(here, expert, eh).reshape(-1),
+                (slot % cap).reshape(-1)].add(rows, mode="drop")
+            yb = hint(ffn(hint(buf, "model")), "model")  # (E_h, C, d)
+            return here, yb[expert, slot % cap]
+
+        v = one_round(0)[1]
+        if cap < t:  # an expert may be routed more rows than one round
+
+            def further(r, v):
+                here, vr = one_round(r)
+                return jnp.where(here[..., None], vr, v)
+
+            v = jax.lax.fori_loop(1, rounds, further, v)
+        w = jnp.where(held, gates, 0.0)
+        y = w[:, 0, None] * v[:, 0].astype(jnp.float32)
+        for j in range(1, k):  # one fixed order of the choices' sum
+            y = y + w[:, j, None] * v[:, j].astype(jnp.float32)
+        out = hint_dp(y).astype(x.dtype)
+    if cfg.n_shared_experts:
+        out = out + _shared_ffn(cfg, params, xt)
+    computed = (jnp.maximum(rounds, 1) * eh * cap).astype(jnp.int32)
+    counts = jnp.stack([per_expert.sum(), computed, per_expert.max()])
+    return out.reshape(b, s, d), counts
 
 
 def _dp_groups(t: int) -> int:
@@ -98,7 +241,6 @@ def moe_apply(cfg: ModelConfig, params: dict, x: jax.Array):
         return moe_apply_dp_local(cfg, params, x)
     b, s, d = x.shape
     t = b * s
-    e = cfg.n_experts
     k = cfg.experts_per_token
     cap = moe_capacity(cfg, t)
     xt = x.reshape(t, d)
@@ -106,19 +248,19 @@ def moe_apply(cfg: ModelConfig, params: dict, x: jax.Array):
     from repro.sharding.ctx import hint, hint_dp
 
     xt = hint_dp(xt)  # tokens stay DP-sharded through routing
-    logits = dense(xt, params["router"]).astype(jnp.float32)  # (T, E)
-    logits = hint_dp(logits)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, k)  # (T, k)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    probs, gate_vals, local, held = _route(cfg, params, xt)  # (T, E), (T, k)
+    probs = hint_dp(probs)
 
-    # slot assignment: position of each (t, i) among assignments to its
-    # expert, in token order (cumsum of one-hot counts).
-    flat_e = expert_idx.reshape(-1)  # (T*k,)
-    onehot = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)  # (T*k, E)
+    # slot assignment: position of each held (t, i) among assignments to
+    # its expert, in token order (cumsum of one-hot counts); choices of
+    # experts held elsewhere take no slot.
+    e = cfg.experts_held
+    flat_e = jnp.where(held, local, 0).reshape(-1)  # (T*k,)
+    onehot = jax.nn.one_hot(jnp.where(held, local, -1).reshape(-1), e,
+                            dtype=jnp.int32)  # (T*k, E_h)
     pos_in_e = jnp.cumsum(onehot, axis=0) - onehot  # exclusive prefix count
     slot = jnp.take_along_axis(pos_in_e, flat_e[:, None], axis=1)[:, 0]  # (T*k,)
-    keep = slot < cap
+    keep = (slot < cap) & held.reshape(-1)
     gates = gate_vals.reshape(-1) * keep
 
     # dispatch: (E, C, d) expert buffers (all-to-all under expert sharding)
@@ -140,17 +282,11 @@ def moe_apply(cfg: ModelConfig, params: dict, x: jax.Array):
     out = hint_dp(y).astype(x.dtype)
 
     if cfg.n_shared_experts:
-        glu = cfg.ffn_kind in ("swiglu", "geglu")
-        h = dense(xt, params["shared_wi"], cfg.l2r, cfg.l2r_levels)
-        if glu:
-            g_, u_ = h[..., 0, :], h[..., 1, :]
-            h = (jax.nn.silu(g_) if cfg.ffn_kind == "swiglu" else jax.nn.gelu(g_)) * u_
-        else:
-            h = jax.nn.gelu(h)
-        out = out + dense(h, params["shared_wo"], cfg.l2r, cfg.l2r_levels)
+        out = out + _shared_ffn(cfg, params, xt)
 
-    # Switch-style load-balance aux loss
-    me = probs.mean(0)  # (E,) mean router prob
+    # Switch-style load-balance aux loss (over the held experts)
+    lo = cfg.expert_offset
+    me = probs[:, lo:lo + e].mean(0)  # (E_h,) mean router prob
     ce = jnp.zeros((e,), jnp.float32).at[flat_e].add(keep.astype(jnp.float32)) / max(t * k, 1)
     aux = e * jnp.sum(me * ce) * cfg.router_aux_weight
     return out.reshape(b, s, d), aux
@@ -170,6 +306,8 @@ def moe_apply_dp_local(cfg: ModelConfig, params: dict, x: jax.Array):
     """
     from repro.sharding.ctx import hint, hint_dp
 
+    assert cfg.experts_held == cfg.n_experts, \
+        "DP-local dispatch shards every expert over the mesh"
     b, s, d = x.shape
     t = b * s
     e = cfg.n_experts
@@ -185,11 +323,8 @@ def moe_apply_dp_local(cfg: ModelConfig, params: dict, x: jax.Array):
     # over "model" for the router/shared-expert matmuls.
     all_axes = ("pod", "data", "model")
     xt = hint(x.reshape(t, d), all_axes)
-    logits = dense(xt, params["router"]).astype(jnp.float32)
-    logits = hint(logits, all_axes)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, k)  # (T, k)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    probs, gate_vals, expert_idx, _ = _route(cfg, params, xt)  # (T, k)
+    probs = hint(probs, all_axes)
 
     xg = xt.reshape(dp, t_local, d)
     eg = expert_idx.reshape(dp, t_local, k)
@@ -234,14 +369,7 @@ def moe_apply_dp_local(cfg: ModelConfig, params: dict, x: jax.Array):
     out = hint(y.reshape(t, d), all_axes).astype(x.dtype)
 
     if cfg.n_shared_experts:
-        glu = cfg.ffn_kind in ("swiglu", "geglu")
-        h = dense(xt, params["shared_wi"], cfg.l2r, cfg.l2r_levels)
-        if glu:
-            g_, u_ = h[..., 0, :], h[..., 1, :]
-            h = (jax.nn.silu(g_) if cfg.ffn_kind == "swiglu" else jax.nn.gelu(g_)) * u_
-        else:
-            h = jax.nn.gelu(h)
-        out = out + dense(h, params["shared_wo"], cfg.l2r, cfg.l2r_levels)
+        out = out + _shared_ffn(cfg, params, xt)
 
     me = probs.mean(0)
     ce = jnp.zeros((e,), jnp.float32).at[flat_e.reshape(-1)].add(

@@ -2,7 +2,8 @@
 
 An architecture is a per-layer sequence of (mixer, ffn) kinds
 (ModelConfig.layer_kinds): mixers are 'global' / 'local' attention,
-'ssd' (Mamba-2), 'rec' (RG-LRU); ffns are 'mlp' / 'moe'.  Layers are
+'mla' (latent attention), 'ssd' (Mamba-2), 'rec' (RG-LRU); ffns are
+'mlp' / 'moe'.  Layers are
 grouped into the smallest repeating unit and executed under lax.scan
 (one traced copy per unit — compile time and HLO size stay bounded for
 62-layer models), with aperiodic prefix/suffix layers unrolled.
@@ -11,6 +12,11 @@ Three modes:
   train   — full sequence, no cache, remat per scanned block;
   prefill — full sequence, writes caches/states;
   decode  — one token against caches/states (O(1) state for ssd/rec/local).
+
+Prefill and decode serve: their MoE layers are dropless
+(models/moe.py:moe_serve), and the serving state of an MoE model
+carries the MoE counters (``LMState.moe_counts``), which decode steps
+accumulate.
 """
 
 from __future__ import annotations
@@ -31,8 +37,9 @@ from .attention import (
 )
 from .common import Param, dense, rms_norm, layer_norm
 from .config import ModelConfig
+from .mla import init_mla_cache, mla_apply, mla_build
 from .mlp import mlp_build, mlp_apply
-from .moe import moe_build, moe_apply
+from .moe import moe_apply, moe_build, moe_serve
 from .rglru import init_rglru_state, rglru_apply, rglru_build, rglru_decode
 from .ssm import init_ssm_state, ssm_apply, ssm_build, ssm_decode
 
@@ -140,6 +147,8 @@ def attn_apply(
 def _mixer_build(cfg: ModelConfig, kind: str) -> dict:
     if kind in ("global", "local"):
         return attn_build(cfg)
+    if kind == "mla":
+        return mla_build(cfg)
     if kind == "ssd":
         return ssm_build(cfg)
     if kind == "rec":
@@ -175,6 +184,9 @@ def _mixer_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int, dtype):
     if kind == "local":
         return init_kv_cache(batch, min(cfg.window, max_len), cfg.n_kv,
                              cfg.head_dim, dtype, quant=cfg.attn_l2r)
+    if kind == "mla":
+        return init_mla_cache(batch, max_len, cfg.kv_lora_rank,
+                              cfg.qk_rope_dim, dtype)
     if kind == "ssd":
         return init_ssm_state(cfg, batch)
     if kind == "rec":
@@ -192,8 +204,13 @@ def layer_apply(
     rope_positions,
     positions,
     cache,
+    valid=None,
 ):
-    """One (mixer + ffn) residual layer. Returns (x, new_cache, aux)."""
+    """One (mixer + ffn) residual layer.  Returns (x, new_cache, aux,
+    counts): ``counts`` is a serving MoE layer's int32 counters
+    (models/moe.py:MOE_COUNTS), else None.  ``valid`` (B, S) marks the
+    rows whose output is used (a serving MoE routes the others
+    nowhere)."""
     mixer_kind, ffn_kind = kinds
     norm = layer_norm_fn(cfg)
     h = norm(x, params["mixer_norm"])
@@ -203,6 +220,10 @@ def layer_apply(
             cfg, params["mixer"], h, mode=mode, rope_positions=rope_positions,
             positions=positions, cache=cache, window=window,
         )
+    elif mixer_kind == "mla":
+        mixed, new_cache = mla_apply(
+            cfg, params["mixer"], h, mode=mode,
+            rope_positions=rope_positions, positions=positions, cache=cache)
     elif mixer_kind == "ssd":
         if mode == "decode":
             mixed, new_cache = ssm_decode(cfg, params["mixer"], h, cache)
@@ -220,16 +241,28 @@ def layer_apply(
     x = x + mixed
 
     aux = jnp.zeros((), jnp.float32)
+    counts = None
     if ffn_kind != "none":
         h = norm(x, params["ffn_norm"])
         if ffn_kind == "moe":
             with jax.named_scope("moe"):
-                out, aux = moe_apply(cfg, params["ffn"], h)
+                if mode == "train":
+                    out, aux = moe_apply(cfg, params["ffn"], h)
+                else:
+                    out, counts = moe_serve(cfg, params["ffn"], h, valid)
         else:
             with jax.named_scope("mlp"):
                 out = mlp_apply(cfg, params["ffn"], h)
         x = x + out
-    return x, new_cache, aux
+    return x, new_cache, aux, counts
+
+
+def _add_counts(a, b):
+    """Fold two MoE counter vectors: sums of rows, max of the per-expert
+    maximum (either may be None)."""
+    if a is None or b is None:
+        return b if a is None else a
+    return jnp.stack([a[0] + b[0], a[1] + b[1], jnp.maximum(a[2], b[2])])
 
 
 def layer_norm_fn(cfg: ModelConfig) -> Callable:
@@ -287,10 +320,14 @@ class LMState:
     stack: Any  # leaves have leading (repeats,) axis
     suffix: list
     pos: jax.Array  # (B,) next position to write
+    # MoE models: int32 (3,) counters (models/moe.py:MOE_COUNTS) that
+    # decode steps accumulate; batch-independent, never spliced per slot
+    moe_counts: jax.Array | None = None
 
 
 jax.tree_util.register_dataclass(
-    LMState, data_fields=["prefix", "stack", "suffix", "pos"], meta_fields=[]
+    LMState, data_fields=["prefix", "stack", "suffix", "pos", "moe_counts"],
+    meta_fields=[]
 )
 
 
@@ -310,6 +347,8 @@ def init_lm_state(cfg: ModelConfig, batch: int, max_len: int,
         stack=stack,
         suffix=[mk(kk) for kk in suffix],
         pos=jnp.zeros((batch,), jnp.int32),
+        moe_counts=(jnp.zeros((3,), jnp.int32)
+                    if "moe" in cfg.ffn_kinds() else None),
     )
 
 
@@ -324,11 +363,14 @@ def lm_forward(
     state: LMState | None = None,
     resid_shard: Callable[[jax.Array], jax.Array] = lambda x: x,
     remat: bool = False,
+    valid: jax.Array | None = None,
 ):
     """Backbone forward.
 
     Returns (hidden (B,S,d), new_state, aux_loss).  `tokens` xor `embeds`
-    (modality-stub archs feed embeddings per the assignment).
+    (modality-stub archs feed embeddings per the assignment).  ``valid``
+    (B, S) marks the positions whose output is used, where a bucket
+    prefill pads (serving MoE layers skip the others).
     """
     prefix_k, repeats, unit, suffix_k = cfg.block_grouping()
     compute_dtype = jnp.dtype(cfg.compute_dtype)
@@ -349,20 +391,22 @@ def lm_forward(
         rope_positions = positions
 
     aux_total = jnp.zeros((), jnp.float32)
+    counts = None
 
     def run_layer(x, lp, kinds, cache):
         return layer_apply(
             cfg, lp, kinds, x, mode=mode, rope_positions=rope_positions,
-            positions=positions, cache=cache,
+            positions=positions, cache=cache, valid=valid,
         )
 
     new_prefix = []
     for i, kk in enumerate(prefix_k):
         c = state.prefix[i] if state is not None else None
-        x, c2, aux = run_layer(x, params["prefix"][i], kk, c)
+        x, c2, aux, cnt = run_layer(x, params["prefix"][i], kk, c)
         x = resid_shard(x)
         new_prefix.append(c2)
         aux_total += aux
+        counts = _add_counts(counts, cnt)
 
     new_stack = None
     if repeats:
@@ -376,7 +420,7 @@ def lm_forward(
         # scopes, 46% of decode device time at 64 slots x 1024 on a TPU
         # v5e (PERF.md §5).
         def block(carry, lp):
-            x, aux_acc, caches_all, blk_i = carry
+            x, aux_acc, caches_all, blk_i, cnt_acc = carry
             if caches_all is not None:
                 with jax.named_scope("kv_cache_read"):
                     caches = jax.tree.map(
@@ -385,11 +429,12 @@ def lm_forward(
                         caches_all)
             new_caches = []
             for u_idx, kk in enumerate(unit):
-                x, c2, aux = run_layer(
+                x, c2, aux, cnt = run_layer(
                     x, lp[u_idx], kk,
                     caches[u_idx] if caches_all is not None else None)
                 new_caches.append(c2)
                 aux_acc = aux_acc + aux
+                cnt_acc = _add_counts(cnt_acc, cnt)
             x = resid_shard(x)
             if caches_all is not None:
                 with jax.named_scope("kv_cache_writeback"):
@@ -397,23 +442,29 @@ def lm_forward(
                         lambda buf, new: jax.lax.dynamic_update_index_in_dim(
                             buf, new.astype(buf.dtype), blk_i, 0),
                         caches_all, new_caches)
-            return (x, aux_acc, caches_all, blk_i + 1), None
+            return (x, aux_acc, caches_all, blk_i + 1, cnt_acc), None
 
         block_fn = jax.checkpoint(block) if remat else block
         caches_in = state.stack if state is not None else None
-        (x, aux_total, new_stack, _), _ = jax.lax.scan(
+        # the scan carries the unit's MoE counters when it serves any
+        cnt0 = (jnp.zeros((3,), jnp.int32)
+                if mode != "train" and any(k[1] == "moe" for k in unit)
+                else None)
+        (x, aux_total, new_stack, _, cnt), _ = jax.lax.scan(
             block_fn,
-            (x, aux_total, caches_in, jnp.zeros((), jnp.int32)),
+            (x, aux_total, caches_in, jnp.zeros((), jnp.int32), cnt0),
             params["stack"],
         )
+        counts = _add_counts(counts, cnt)
 
     new_suffix = []
     for i, kk in enumerate(suffix_k):
         c = state.suffix[i] if state is not None else None
-        x, c2, aux = run_layer(x, params["suffix"][i], kk, c)
+        x, c2, aux, cnt = run_layer(x, params["suffix"][i], kk, c)
         x = resid_shard(x)
         new_suffix.append(c2)
         aux_total += aux
+        counts = _add_counts(counts, cnt)
 
     x = layer_norm_fn(cfg)(x, params["final_norm"])
 
@@ -422,6 +473,7 @@ def lm_forward(
         new_state = LMState(
             prefix=new_prefix, stack=new_stack, suffix=new_suffix,
             pos=state.pos + s,
+            moe_counts=_add_counts(state.moe_counts, counts),
         )
     return x, new_state, aux_total
 

@@ -21,6 +21,7 @@ from repro.core.progressive import streaming_argmax
 from repro.core.quant import QuantConfig, QuantizedWeights, quantize
 from repro.models.attention import KVCache
 from repro.models.config import ModelConfig
+from repro.models.mla import MLACache
 from repro.models.encdec import (EncDecState, encdec_forward,
                                  init_encdec_state)
 from repro.models.transformer import (LMState, init_lm_state, lm_forward,
@@ -80,6 +81,10 @@ def prepare_params(cfg: ModelConfig, params, desc=None, prestack: bool = True,
     per-step resharding.  Backbone weights stay replicated (activations
     are batch-sharded instead; the head is the one vocab-axis matmul of
     every decode step).  Sharding never changes values.
+
+    Routed-expert stacks quantize per expert (models/common.py:
+    quantize_tree); MoE routers and MLA's latent up-projection stay
+    float (models/common.py:_quantizable).
 
     ``desc`` is the Param descriptor tree (for eligibility); defaults to
     rebuilding it from ``cfg`` for LM families.
@@ -171,6 +176,9 @@ def state_specs(cfg: ModelConfig, mesh: Mesh, batch: int, max_len: int,
     def mixer_spec(kind: str):
         if kind in ("global", "local"):
             return kv_spec()
+        if kind == "mla":  # one latent per position: shards with batch
+            return MLACache(c_kv=P(b, None, None), k_pe=P(b, None, None),
+                            positions=P(b, None))
         if kind == "ssd":
             d_inner = cfg.ssm_expand * cfg.d_model
             conv_dim = d_inner + 2 * cfg.ssm_state
@@ -206,6 +214,7 @@ def state_specs(cfg: ModelConfig, mesh: Mesh, batch: int, max_len: int,
         stack=stack,
         suffix=[mixer_spec(kk[0]) for kk in suffix],
         pos=P(b),
+        moe_counts=P() if "moe" in cfg.ffn_kinds() else None,
     )
 
 
@@ -389,13 +398,14 @@ def bucket_for(length: int, buckets: tuple[int, ...]) -> int:
 
 def supports_bucketed_prefill(cfg: ModelConfig) -> bool:
     """Bucketed (right-padded) prefill is exact only for attention
-    mixers: causal masking makes pad positions invisible to every real
-    position, and the pad cache entries can be marked empty afterwards.
-    Recurrent mixers (ssd / rec) carry the state at the LAST position —
-    pad tokens would contaminate it — so those families keep the
-    exact-length prefill path."""
+    mixers (``global``, ``local``, ``mla``): causal masking makes pad
+    positions invisible to every real position, and the pad cache
+    entries can be marked empty afterwards.  Recurrent mixers (ssd /
+    rec) carry the state at the LAST position — pad tokens would
+    contaminate it — so those families keep the exact-length prefill
+    path."""
     return cfg.family != "encdec" and all(
-        k in ("global", "local") for k, _ in cfg.layer_kinds())
+        k in ("global", "local", "mla") for k, _ in cfg.layer_kinds())
 
 
 def _mask_bucket_state(state: LMState, true_len: jax.Array) -> LMState:
@@ -410,17 +420,18 @@ def _mask_bucket_state(state: LMState, true_len: jax.Array) -> LMState:
     #   against (B, L) and stacked (layers, B, L) position leaves alike
 
     def fix(c):
-        if not isinstance(c, KVCache):
+        if not isinstance(c, (KVCache, MLACache)):
             return c
         return c._replace(
             positions=jnp.where(c.positions < tl, c.positions, -1))
 
-    is_kv = lambda x: isinstance(x, KVCache)
+    is_kv = lambda x: isinstance(x, (KVCache, MLACache))
     return LMState(
         prefix=jax.tree.map(fix, state.prefix, is_leaf=is_kv),
         stack=jax.tree.map(fix, state.stack, is_leaf=is_kv),
         suffix=jax.tree.map(fix, state.suffix, is_leaf=is_kv),
         pos=true_len.astype(jnp.int32),
+        moe_counts=state.moe_counts,
     )
 
 
@@ -480,10 +491,15 @@ def make_bucket_prefill_step(cfg: ModelConfig, max_len: int,
                 f"{cfg.window}: the ring cache would wrap over real "
                 f"prompt entries")
         state = init_lm_state(cfg, bsz, max_len, cache_dtype)
+        # an MoE layer routes the pad positions nowhere
+        valid = (jnp.arange(lb)[None] < true_len[:, None]
+                 if "moe" in cfg.ffn_kinds() else None)
         fwd = _replicated_backbone(
-            lambda p, s, t: lm_forward(cfg, p, tokens=t, mode="prefill",
-                                       state=s), mesh, backbone_hints)
-        hidden, state, _ = fwd(_backbone_params(params), state, tokens)
+            lambda p, s, t, v: lm_forward(cfg, p, tokens=t, mode="prefill",
+                                          state=s, valid=v),
+            mesh, backbone_hints)
+        hidden, state, _ = fwd(_backbone_params(params), state, tokens,
+                               valid)
         idx = (true_len.astype(jnp.int32) - 1)[:, None, None]
         h_last = jnp.take_along_axis(hidden, idx, axis=1)  # (B, 1, d)
         state = _mask_bucket_state(state, true_len)

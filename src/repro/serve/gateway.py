@@ -42,7 +42,10 @@ prefill, so TTFT splits into queue wait (``t_admit - t_arrival``) and
 first-token lag (``t_first_token - t_admit``: device backlog, prefill,
 emit).  ``stats()["decode_in_flight_mean"]`` is the mean number of
 decode steps dispatched but not yet landed at each decode dispatch: how
-far the loop runs ahead of the device.  ``jax.profiler.TraceAnnotation``
+far the loop runs ahead of the device.  For an MoE model,
+``stats()["moe"]`` reads the counters that decode steps accumulate on
+the device (models/moe.py:MOE_COUNTS), once per call.
+``jax.profiler.TraceAnnotation``
 spans, which record only inside an active profile, put the host loop on
 the device trace's clock: ``gateway.admit`` (one prefill group, with its
 request uids), ``gateway.decode`` (one decode dispatch),
@@ -71,6 +74,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.policy import LevelPolicy, PrecisionClass
 from repro.models.config import ModelConfig
+from repro.models.moe import MOE_COUNTS
 from repro.models.transformer import init_lm_state
 from .batching import (Request, _splice, latency_percentiles,
                        progressive_stats, state_batch_axes)
@@ -362,9 +366,11 @@ class ServingGateway:
         """Gateway counters (emit-thread flushed first): dispatch and
         token counts, throughput, the mean number of decode steps in
         flight at a decode dispatch, progressive saved-levels histograms
-        (same schema as `ContinuousBatcher.stats`), and — unless
-        ``latency=False`` — p50/p99 TTFT and per-output-token seconds
-        over completed requests."""
+        (same schema as `ContinuousBatcher.stats`), an MoE model's
+        decode counters since construction (``moe``: every slot of every
+        decode step, idle ones included), and — unless ``latency=False``
+        — p50/p99 TTFT and per-output-token seconds over completed
+        requests."""
         self._flush_emit()
         out = {"steps": self.steps, "prefills": self.prefills,
                "progressive": self.progressive, "tokens": self._tokens,
@@ -375,6 +381,9 @@ class ServingGateway:
                "decode_in_flight_mean": (
                    self._in_flight_sum / self._in_flight_n
                    if self._in_flight_n else 0.0)}
+        if self.state.moe_counts is not None:
+            out["moe"] = dict(zip(MOE_COUNTS, np.asarray(
+                self.state.moe_counts).tolist()))
         if self.progressive:
             out.update(progressive_stats(self.n_levels, self.exit_hist,
                                          self.prefill_exit_hist,

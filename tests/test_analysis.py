@@ -249,7 +249,12 @@ def test_quantize_weights_guard_strict(monkeypatch):
 
 def test_registry_sweep_all_sound():
     rows = overflow.audit_registry()
-    assert len(rows) == 20  # 10 archs x (head, attention)
+    # 11 archs x (head, attention), and the three MoE archs' expert,
+    # shared-expert and dense-layer down projections
+    assert len(rows) == 31
+    v2 = {r["site"]: r for r in rows if r["arch"] == "deepseek-v2-lite"}
+    assert {s: v2[s]["k"] for s in ("head", "expert_down", "shared_down")} \
+        == {"head": 2048, "expert_down": 1408, "shared_down": 2816}
     assert all(r["sound"] for r in rows), \
         [r for r in rows if not r["sound"]]
 

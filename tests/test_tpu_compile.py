@@ -127,3 +127,56 @@ def test_kernels_carry_their_layer_name(one_chip):
     text = _compile(fc, one_chip, ((8, 512), jnp.int8),
                     ((512, 256), jnp.int8))
     assert re.search(r"%l2r_gemm_pallas_stacked_planes_fc6(\.\d+)? = ", text)
+
+
+def test_held_experts_gemm_published_widths(one_chip):
+    """DeepSeek-V2-Lite's held experts at decode: the stacked kernel
+    vmapped over 8 experts, 256 slot rows each, up 2048 -> 2 x 1408 and
+    down 1408 -> 2048 (K padded to 1536), named for the experts."""
+    import re
+
+    kd = 1408 + (-1408) % 256
+
+    def experts(a, b):
+        return jax.vmap(lambda x, w: l2r_gemm_pallas_stacked_planes(
+            x, w, 8, 2, name="experts"))(a, b)
+    for k, n in ((2048, 2 * 1408), (kd, 2048)):
+        text = _compile(experts, one_chip, ((8, 256, D * k), jnp.int8),
+                        ((8, D * k, n), jnp.int8))
+        assert re.search(
+            r"%l2r_gemm_pallas_stacked_planes_experts(\.\d+)? = ", text)
+
+
+def test_mla_decode_step_256_slots(one_chip, monkeypatch):
+    """One DeepSeek-V2-Lite MLA layer's decode step at 256 slots over a
+    1024-position float32 latent cache: the L2R projections on the
+    compiled kernels, the absorbed attention around them."""
+    import dataclasses
+
+    import repro.kernels.l2r_gemm.ops as ops
+    from repro.configs import get_config
+    from repro.core.quant import QuantConfig
+    from repro.models.common import abstract
+    from repro.models.mla import init_mla_cache, mla_apply, mla_build
+
+    # the dispatcher would pick the CPU's jnp path: steer it to the chip's
+    monkeypatch.setattr(ops, "_resolve", lambda backend: "pallas-tpu")
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite"),
+                              l2r=QuantConfig())
+    b, length = 256, 1024
+
+    def step(p, x, cache, pos):
+        return mla_apply(cfg, p, x, mode="decode", rope_positions=pos,
+                         positions=pos, cache=cache)
+
+    def sds(t):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), t)
+    params = sds(abstract(mla_build(cfg)))
+    cache = sds(jax.eval_shape(lambda: init_mla_cache(
+        b, length, cfg.kv_lora_rank, cfg.qk_rope_dim, jnp.float32)))
+    x = jax.ShapeDtypeStruct((b, 1, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((b, 1), jnp.int32, sharding=one_chip)
+    text = jax.jit(step).lower(params, x, cache, pos).compile().as_text()
+    assert "tpu_custom_call" in text
