@@ -34,6 +34,8 @@ __all__ = [
     "KVCache",
     "init_kv_cache",
     "update_kv_cache",
+    "write_slots",
+    "cache_layer",
     "kv_plane_operands",
 ]
 
@@ -472,13 +474,50 @@ def init_kv_cache(batch: int, length: int, kv_heads: int, head_dim: int,
     )
 
 
+def write_slots(buf: jax.Array, slots: jax.Array, new: jax.Array,
+                layer: jax.Array | None = None) -> jax.Array:
+    """``buf`` (B, L, ...) with ``new`` (B, S, ...) written at ``slots``
+    (B, S); a slot outside [0, L) is dropped.
+
+    With ``layer``, ``buf`` is a leaf of a layer-stacked cache (layers,
+    B, L, ...) riding a scan carry, and ``new`` holds one position a row
+    (decode).  The layer's slab takes each row's entry by a select
+    against the slot and goes back where it came from, one fusion that
+    XLA performs in place in whatever layout it keeps the stack, and
+    that GSPMD keeps on each shard.  A scatter would not do: on a TPU it
+    first relayouts a stack whose position axis lies minor-most
+    (SmolLM's 3 x 64 heads) and copies it whole around the scan."""
+    if layer is None:
+        return jax.vmap(lambda b, s, n: b.at[s].set(n))(buf, slots, new)
+    assert slots.shape[1] == 1, "in place: one new position a row"
+    slab = jax.lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
+    hit = jnp.arange(slab.shape[1]) == slots  # (B, L)
+    hit = hit.reshape(hit.shape + (1,) * (slab.ndim - 2))
+    return jax.lax.dynamic_update_index_in_dim(
+        buf, jnp.where(hit, new, slab), layer, 0)
+
+
+def cache_layer(cache, layer: jax.Array | None):
+    """Layer ``layer``'s slab of a layer-stacked cache (any NamedTuple
+    of stacked leaves), sliced where it lies for its consumer to read;
+    the cache itself where ``layer`` is None."""
+    if layer is None:
+        return cache
+    return jax.tree.map(
+        lambda buf: jax.lax.dynamic_index_in_dim(buf, layer, 0,
+                                                 keepdims=False), cache)
+
+
 def update_kv_cache(cache: KVCache, k_new: jax.Array, v_new: jax.Array,
                     positions: jax.Array,
-                    quant: QuantConfig | None = None) -> KVCache:
+                    quant: QuantConfig | None = None,
+                    layer: jax.Array | None = None) -> KVCache:
     """Insert S new entries at slots positions % L (ring semantics; for a
     full-length cache L >= max position this is plain indexed write).
 
-    k_new/v_new: (B, S, Kv, dh); positions: (B, S) absolute.
+    k_new/v_new: (B, S, Kv, dh); positions: (B, S) absolute.  ``layer``
+    marks a layer-stacked cache (every leaf with a leading layers axis):
+    the entries go into that layer in place (:func:`write_slots`).
 
     A plane-stacked cache (``init_kv_cache(..., quant=...)``) must pass
     the same ``quant`` here: the new keys' digit planes append into the
@@ -487,10 +526,10 @@ def update_kv_cache(cache: KVCache, k_new: jax.Array, v_new: jax.Array,
     incremental stack is bit-identical to re-extracting planes from the
     full float cache at any later step.
     """
-    length = cache.k.shape[1]
+    length = cache.positions.shape[-1]
     slots = positions % length  # (B, S)
     def write(buf, new):
-        return jax.vmap(lambda b, s, n: b.at[s].set(n))(buf, slots, new)
+        return write_slots(buf, slots, new, layer)
     k_planes, k_scale = cache.k_planes, cache.k_scale
     if k_planes is not None:
         assert quant is not None, \
@@ -508,9 +547,7 @@ def update_kv_cache(cache: KVCache, k_new: jax.Array, v_new: jax.Array,
     return KVCache(
         k=write(cache.k, k_new.astype(cache.k.dtype)),
         v=write(cache.v, v_new.astype(cache.v.dtype)),
-        positions=jax.vmap(lambda p, s, n: p.at[s].set(n))(
-            cache.positions, slots, positions
-        ),
+        positions=write(cache.positions, positions),
         k_planes=k_planes,
         k_scale=k_scale,
     )

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .attention import cache_layer, write_slots
 from .common import Param, dense, rms_norm
 from .config import ModelConfig
 
@@ -70,11 +71,13 @@ def init_mla_cache(batch: int, length: int, rank: int, rope_dim: int,
 
 
 def update_mla_cache(cache: MLACache, c_kv: jax.Array, k_pe: jax.Array,
-                     positions: jax.Array) -> MLACache:
-    """Write S new entries at slots ``positions`` (B, S)."""
+                     positions: jax.Array,
+                     layer: jax.Array | None = None) -> MLACache:
+    """Write S new entries at slots ``positions`` (B, S); with ``layer``,
+    one a row into that layer of a layer-stacked cache, in place
+    (attention.py:write_slots)."""
     def write(buf, new):
-        return jax.vmap(lambda b, s, n: b.at[s].set(n))(
-            buf, positions, new.astype(buf.dtype))
+        return write_slots(buf, positions, new.astype(buf.dtype), layer)
     return MLACache(c_kv=write(cache.c_kv, c_kv),
                     k_pe=write(cache.k_pe, k_pe),
                     positions=write(cache.positions, positions))
@@ -150,8 +153,10 @@ def mla_build(cfg: ModelConfig) -> dict:
 @jax.named_scope("mla")
 def mla_apply(cfg: ModelConfig, p: dict, x: jax.Array, *, mode: str,
               rope_positions: jax.Array, positions: jax.Array,
-              cache: MLACache | None):
-    """One MLA layer on x (B, S, d).  Returns (out, new_cache)."""
+              cache: MLACache | None, layer: jax.Array | None = None):
+    """One MLA layer on x (B, S, d).  Returns (out, new_cache).  With
+    ``layer`` (decode), ``cache`` is layer-stacked: the layer's entry is
+    written and its latents read where they lie in it."""
     b, s, _ = x.shape
     h, r = cfg.n_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
@@ -168,17 +173,18 @@ def mla_apply(cfg: ModelConfig, p: dict, x: jax.Array, *, mode: str,
 
     if mode == "decode":
         with jax.named_scope("mla_cache_update"):
-            cache = update_mla_cache(cache, c_kv, k_pe, positions)
+            cache = update_mla_cache(cache, c_kv, k_pe, positions, layer)
         # absorbed: each head's query moves into the latent
         q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, w_kvb[..., :dn])
         with jax.named_scope("mla_cache_read"):
-            c = cache.c_kv.astype(x.dtype)
+            view = cache_layer(cache, layer)
+            c = view.c_kv.astype(x.dtype)
             sc = (jnp.einsum("bshr,btr->bhst", q_lat, c,
                              preferred_element_type=f32)
                   + jnp.einsum("bshd,btd->bhst", q_pe,
-                               cache.k_pe.astype(x.dtype),
+                               view.k_pe.astype(x.dtype),
                                preferred_element_type=f32)) * scale
-            kp = cache.positions[:, None, None, :]
+            kp = view.positions[:, None, None, :]
             valid = (kp >= 0) & (kp <= positions[:, None, :, None])
             w = jax.nn.softmax(jnp.where(valid, sc, _NEG), axis=-1)
             o_lat = jnp.einsum("bhst,btr->bshr", w.astype(x.dtype), c)

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from .attention import (
     KVCache,
     apply_rope,
+    cache_layer,
     chunked_attention,
     decode_attention,
     init_kv_cache,
@@ -37,7 +38,7 @@ from .attention import (
 )
 from .common import Param, dense, rms_norm, layer_norm
 from .config import ModelConfig
-from .mla import init_mla_cache, mla_apply, mla_build
+from .mla import MLACache, init_mla_cache, mla_apply, mla_build
 from .mlp import mlp_build, mlp_apply
 from .moe import moe_apply, moe_build, moe_serve
 from .rglru import init_rglru_state, rglru_apply, rglru_build, rglru_decode
@@ -80,10 +81,14 @@ def attn_apply(
     cache: KVCache | None,
     window: int | None,
     cross_kv: tuple[jax.Array, jax.Array] | None = None,
+    layer: jax.Array | None = None,
 ):
     """Self- or cross-attention layer.  Returns (out, new_cache).  Runs
     under the ``attention`` named scope, its cache write under
-    ``kv_cache_update``."""
+    ``kv_cache_update`` and decode's slice of the cache under
+    ``kv_cache_read``.  With ``layer`` (decode), ``cache`` is
+    layer-stacked (a scan carry): the new entry is written into that
+    layer in place and the layer's slab is read where it lies."""
     b, s, _ = x.shape
     h, kv, dh = cfg.n_heads, cfg.n_kv, cfg.head_dim
 
@@ -117,13 +122,15 @@ def attn_apply(
     if mode == "decode":
         with jax.named_scope("kv_cache_update"):
             cache = update_kv_cache(cache, k, v, positions,
-                                    quant=cfg.attn_l2r)
+                                    quant=cfg.attn_l2r, layer=layer)
+        with jax.named_scope("kv_cache_read"):
+            view = cache_layer(cache, layer)
         out = decode_attention(
-            q, cache.k, cache.v, cache.positions, positions[:, 0],
+            q, view.k, view.v, view.positions, positions[:, 0],
             window=window, scale=cfg.attn_scale, softcap=cfg.logit_softcap,
             l2r=cfg.attn_l2r, levels=cfg.attn_levels,
             early_exit=cfg.attn_early_exit, exit_tol=cfg.attn_exit_tol,
-            k_planes=cache.k_planes, k_scale=cache.k_scale,
+            k_planes=view.k_planes, k_scale=view.k_scale,
         ).astype(q.dtype)  # a wider cache must not widen the residual
     else:
         if mode == "prefill":
@@ -205,12 +212,15 @@ def layer_apply(
     positions,
     cache,
     valid=None,
+    layer=None,
 ):
     """One (mixer + ffn) residual layer.  Returns (x, new_cache, aux,
     counts): ``counts`` is a serving MoE layer's int32 counters
     (models/moe.py:MOE_COUNTS), else None.  ``valid`` (B, S) marks the
     rows whose output is used (a serving MoE routes the others
-    nowhere)."""
+    nowhere).  ``layer`` marks a decoding attention cache (``KVCache``
+    or ``MLACache``) that is layer-stacked: the mixer writes and reads
+    that layer of it in place."""
     mixer_kind, ffn_kind = kinds
     norm = layer_norm_fn(cfg)
     h = norm(x, params["mixer_norm"])
@@ -218,12 +228,13 @@ def layer_apply(
         window = cfg.window if mixer_kind == "local" else None
         mixed, new_cache = attn_apply(
             cfg, params["mixer"], h, mode=mode, rope_positions=rope_positions,
-            positions=positions, cache=cache, window=window,
+            positions=positions, cache=cache, window=window, layer=layer,
         )
     elif mixer_kind == "mla":
         mixed, new_cache = mla_apply(
             cfg, params["mixer"], h, mode=mode,
-            rope_positions=rope_positions, positions=positions, cache=cache)
+            rope_positions=rope_positions, positions=positions, cache=cache,
+            layer=layer)
     elif mixer_kind == "ssd":
         if mode == "decode":
             mixed, new_cache = ssm_decode(cfg, params["mixer"], h, cache)
@@ -393,10 +404,10 @@ def lm_forward(
     aux_total = jnp.zeros((), jnp.float32)
     counts = None
 
-    def run_layer(x, lp, kinds, cache):
+    def run_layer(x, lp, kinds, cache, layer=None):
         return layer_apply(
             cfg, lp, kinds, x, mode=mode, rope_positions=rope_positions,
-            positions=positions, cache=cache, valid=valid,
+            positions=positions, cache=cache, valid=valid, layer=layer,
         )
 
     new_prefix = []
@@ -410,38 +421,44 @@ def lm_forward(
 
     new_stack = None
     if repeats:
-        # Caches ride the scan CARRY and are updated in place with
-        # dynamic_update_index_in_dim: XLA aliases while-loop carries, so
-        # decode/prefill never copies the full stacked KV cache at once
-        # (the xs/ys formulation materialized a whole-cache copy per
-        # step — 42% of baseline decode HBM traffic; EXPERIMENTS.md
-        # §Perf).  Each block still copies its layer's cache out of the
-        # carry and back: the ``kv_cache_read`` / ``kv_cache_writeback``
-        # scopes, 46% of decode device time at 64 slots x 1024 on a TPU
-        # v5e (PERF.md §5).
+        # Caches ride the scan CARRY (the xs/ys formulation materialized a
+        # whole-cache copy per step — 42% of baseline decode HBM traffic;
+        # EXPERIMENTS.md §Perf).  A decoding attention cache (KVCache,
+        # MLACache) never leaves it: the layer gets the stacked cache and
+        # the block index, writes the new position in place
+        # (attention.py:write_slots) and reads its slab where it lies
+        # (cache_layer).  Slicing each layer's cache out and writing it
+        # back took 46% of decode device time at 64 slots x 1024 on a TPU
+        # v5e (PERF.md §5).  Prefill (a group of a few rows) and the SSM
+        # and RG-LRU states (small, replaced whole every step) keep that
+        # round trip.
+        in_place = mode == "decode"
+
         def block(carry, lp):
             x, aux_acc, caches_all, blk_i, cnt_acc = carry
+            caches = layers = [None] * len(unit)
             if caches_all is not None:
+                layers = [blk_i if in_place and isinstance(
+                    c, (KVCache, MLACache)) else None for c in caches_all]
                 with jax.named_scope("kv_cache_read"):
-                    caches = jax.tree.map(
-                        lambda buf: jax.lax.dynamic_index_in_dim(
-                            buf, blk_i, 0, keepdims=False),
-                        caches_all)
+                    caches = [c if li is not None else cache_layer(c, blk_i)
+                              for c, li in zip(caches_all, layers)]
             new_caches = []
             for u_idx, kk in enumerate(unit):
-                x, c2, aux, cnt = run_layer(
-                    x, lp[u_idx], kk,
-                    caches[u_idx] if caches_all is not None else None)
+                x, c2, aux, cnt = run_layer(x, lp[u_idx], kk, caches[u_idx],
+                                            layers[u_idx])
                 new_caches.append(c2)
                 aux_acc = aux_acc + aux
                 cnt_acc = _add_counts(cnt_acc, cnt)
             x = resid_shard(x)
             if caches_all is not None:
                 with jax.named_scope("kv_cache_writeback"):
-                    caches_all = jax.tree.map(
-                        lambda buf, new: jax.lax.dynamic_update_index_in_dim(
-                            buf, new.astype(buf.dtype), blk_i, 0),
-                        caches_all, new_caches)
+                    caches_all = [
+                        new if li is not None else jax.tree.map(
+                            lambda buf, n: jax.lax.dynamic_update_index_in_dim(
+                                buf, n.astype(buf.dtype), blk_i, 0), old, new)
+                        for old, new, li in zip(caches_all, new_caches,
+                                                layers)]
             return (x, aux_acc, caches_all, blk_i + 1, cnt_acc), None
 
         block_fn = jax.checkpoint(block) if remat else block

@@ -76,9 +76,11 @@ def test_progressive_precision_serving_is_exact_at_full_levels():
 
 
 def test_decode_step_scopes_in_lowered_text():
-    """The decode step's attention, KV-cache read, update and write-back,
-    MLP and head carry named scopes in the compiled program's op_name
-    metadata."""
+    """The decode step's attention, KV-cache update and read, MLP and
+    head carry named scopes in the compiled program's op_name metadata.
+    The update writes the new position into the stacked cache in place
+    (its dynamic-update-slices output the whole stack), the read slices
+    the layer's slab where it lies, and no slab is written back."""
     import re
 
     cfg = dataclasses.replace(get_smoke("smollm-135m"), l2r=QuantConfig())
@@ -93,5 +95,119 @@ def test_decode_step_scopes_in_lowered_text():
     for scope in ("attention", "mlp", "head"):
         assert (scope, "dot_general") in scoped, scope
     assert ("kv_cache_read", "dynamic_slice") in scoped
-    assert ("kv_cache_update", "scatter") in scoped
-    assert ("kv_cache_writeback", "dynamic_update_slice") in scoped
+    assert ("kv_cache_update", "dynamic_update_slice") in scoped
+    assert "kv_cache_writeback" not in text
+    # (layers, slots, positions, ...): k, v and positions of the stack
+    stack = ",".join(map(str, st.stack[0].positions.shape))
+    writes = re.findall(r"\[([\d,]*)\]\S* dynamic-update-slice\(.*"
+                        r'op_name="[^"]*kv_cache_update/', text)
+    assert writes and all(w.startswith(stack) for w in writes), writes
+
+
+def _per_layer_forward(cfg, params, state, tokens, mode):
+    """``lm_forward(mode=...)`` with every layer's cache unstacked and
+    written by ``update_kv_cache`` / ``update_mla_cache`` without a layer
+    index: the stacked layers run as a scan whose inputs and outputs
+    (not carry) are the per-layer caches.  A scan, not an unrolled loop,
+    so that XLA compiles each layer's arithmetic as it does in
+    ``lm_forward`` (an unrolled loop differs in the last bits).  Returns
+    (hidden, state)."""
+    from repro.models.transformer import (LMState, _add_counts, layer_apply,
+                                          layer_norm_fn)
+
+    prefix_k, repeats, unit, suffix_k = cfg.block_grouping()
+    x = params["embed"].astype(jnp.dtype(cfg.compute_dtype))[tokens]
+    if cfg.scale_embeddings:
+        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    positions = state.pos[:, None] + jnp.arange(tokens.shape[1])[None]
+
+    def run(x, cnt, lp, kk, c):
+        x, c, _, n = layer_apply(cfg, lp, kk, x, mode=mode,
+                                 rope_positions=positions,
+                                 positions=positions, cache=c)
+        return x, _add_counts(cnt, n), c
+
+    counts, prefix, suffix, stack = None, [], [], None
+    for i, kk in enumerate(prefix_k):
+        x, counts, c = run(x, counts, params["prefix"][i], kk,
+                           state.prefix[i])
+        prefix.append(c)
+    if repeats:
+        def body(carry, layer):
+            x, cnt = carry
+            lp, caches = layer
+            out = []
+            for u, kk in enumerate(unit):
+                x, cnt, c = run(x, cnt, lp[u], kk, caches[u])
+                out.append(c)
+            return (x, cnt), out
+
+        cnt0 = (jnp.zeros((3,), jnp.int32)
+                if any(k[1] == "moe" for k in unit) else None)
+        (x, cnt), stack = jax.lax.scan(body, (x, cnt0),
+                                       (params["stack"], state.stack))
+        counts = _add_counts(counts, cnt)
+    for i, kk in enumerate(suffix_k):
+        x, counts, c = run(x, counts, params["suffix"][i], kk,
+                           state.suffix[i])
+        suffix.append(c)
+    x = layer_norm_fn(cfg)(x, params["final_norm"])
+    return x, LMState(prefix=prefix, stack=stack, suffix=suffix,
+                      pos=state.pos + tokens.shape[1],
+                      moe_counts=_add_counts(state.moe_counts, counts))
+
+
+@pytest.mark.parametrize("arch,n_layers,attn_l2r", [
+    ("smollm-135m", None, False),        # full GQA caches
+    ("gemma3-27b", 14, False),           # ring caches that wrap (window 16)
+    ("smollm-135m", None, True),         # plane-stacked key cache
+    ("deepseek-v2-lite", None, False),   # MLA latent caches
+    ("recurrentgemma-2b", 8, False),     # RG-LRU states beside a ring cache
+], ids=["gqa", "ring", "planes", "mla", "hybrid"])
+def test_stacked_caches_in_place_match_per_layer_loop(arch, n_layers,
+                                                      attn_l2r):
+    """Prefill and decode through the stacked scan, whose decode writes
+    and reads each attention cache inside the carry, match a per-layer
+    loop over unstacked caches bit for bit: hidden states, logits,
+    tokens and every cache leaf.  Every case stacks at least two blocks,
+    so a write or read of the wrong block shows."""
+    from repro.models.transformer import logits_from_hidden
+    from repro.serve.engine import SERVE_COMPILER_OPTIONS
+
+    cfg = get_smoke(arch)
+    if n_layers:
+        cfg = dataclasses.replace(cfg, n_layers=n_layers)
+    assert cfg.block_grouping()[1] >= 2
+    if attn_l2r:
+        cfg = dataclasses.replace(cfg, attn_l2r=QuantConfig())
+    params = materialize(lm_build(cfg), jax.random.PRNGKey(0))
+    rng = np.random.default_rng(7)
+    toks = jnp.asarray(rng.integers(0, cfg.vocab, (2, 12)), jnp.int32)
+    jit = lambda f: jax.jit(f, compiler_options=SERVE_COMPILER_OPTIONS)  # noqa: E731
+
+    def scanned(mode):
+        return jit(lambda p, s, t: lm_forward(cfg, p, tokens=t, mode=mode,
+                                              state=s)[:2])
+
+    def looped(mode):
+        return jit(lambda p, s, t: _per_layer_forward(cfg, p, s, t, mode))
+
+    def same(a, b):
+        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b), strict=True):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+    st = init_lm_state(cfg, 2, max_len=24, dtype=jnp.float32)
+    h_s, st_s = scanned("prefill")(params, st, toks)
+    h_l, st_l = looped("prefill")(params, st, toks)
+    same((h_s, st_s), (h_l, st_l))
+    tok_s = tok_l = toks[:, -1:]
+    dec_s, dec_l = scanned("decode"), looped("decode")
+    for _ in range(6):  # positions 12..17: the window-16 rings wrap
+        h_s, st_s = dec_s(params, st_s, tok_s)
+        h_l, st_l = dec_l(params, st_l, tok_l)
+        lg_s = logits_from_hidden(cfg, params, h_s)
+        lg_l = logits_from_hidden(cfg, params, h_l)
+        same((h_s, lg_s, st_s), (h_l, lg_l, st_l))
+        tok_s = jnp.argmax(lg_s, -1).astype(jnp.int32)
+        tok_l = jnp.argmax(lg_l, -1).astype(jnp.int32)
+        same(tok_s, tok_l)

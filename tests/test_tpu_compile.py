@@ -147,10 +147,104 @@ def test_held_experts_gemm_published_widths(one_chip):
             r"%l2r_gemm_pallas_stacked_planes_experts(\.\d+)? = ", text)
 
 
+def _decode_keeps_stack_in_place(cfg, one_chip, b, length):
+    """Compile the backbone's decode step (``lm_forward``, as the serving
+    step runs it) at ``b`` slots over ``length`` float32 positions, and
+    check that each stacked attention cache stays in the scan carry: no
+    op outside a fusion outputs one layer's slab of a cache leaf, the
+    only ops that output a whole stack are the in-place writes (a
+    dynamic-update-slice, alone or in its fusion, aliased to the carried
+    buffer), and the temporaries stay under one layer's slab of every
+    cache leaf."""
+    import re
+
+    from repro.models.common import abstract
+    from repro.models.transformer import init_lm_state, lm_build, lm_forward
+    from repro.serve.engine import SERVE_COMPILER_OPTIONS, prepare_params
+
+    def sds(t):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), t)
+    params = sds(jax.eval_shape(lambda p: prepare_params(cfg, p),
+                                abstract(lm_build(cfg))))
+    state = sds(jax.eval_shape(
+        lambda: init_lm_state(cfg, b, length, jnp.float32)))
+    tok = jax.ShapeDtypeStruct((b, 1), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, s, t: lm_forward(cfg, p, tokens=t,
+                                              mode="decode", state=s)[:2],
+                   donate_argnums=(1,),
+                   compiler_options=SERVE_COMPILER_OPTIONS)
+    compiled = step.lower(params, state, tok).compile()
+    text = compiled.as_text()
+
+    leaves = [x for x in jax.tree.leaves(state.stack) if x.ndim > 3]
+    stacks = {tuple(x.shape) for x in leaves}
+    slabs = {tuple(x.shape[1:]) for x in leaves}
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", text))
+    in_place, body, faults, writes = set(), None, [], 0
+    for line in text.splitlines():  # fusions that write a slice in place
+        head = re.match(r"%([\w.\-]+) .*\{$", line)
+        body = head.group(1) if head else body
+        if " dynamic-update-slice(" in line:
+            in_place.add(body)
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            body = head.group(1)
+            continue
+        op = re.match(r"\s+(?:ROOT )?%\S+ = (\(.*?\)|\S+) ([\w\-]+)\(",
+                      line)
+        if body in fused or not op or op.group(2) in (
+                "parameter", "get-tuple-element", "bitcast", "tuple",
+                "while", "conditional", "call", "opt-barrier"):
+            continue  # they pass buffers on
+        shapes = [tuple(int(d) for d in dims.split(",") if d)
+                  for dims in re.findall(r"\w+\[([\d,]*)\]", op.group(1))]
+        callee = re.search(r"calls=%([\w.\-]+)", line)
+        if any(x in stacks for x in shapes) and (
+                op.group(2) == "dynamic-update-slice"
+                or (callee and callee.group(1) in in_place)):
+            writes += 1
+            continue
+        for shape in shapes:
+            while shape[:1] == (1,):
+                shape = shape[1:]
+            # a whole stack, or a stacked layer's slab in any dtype
+            # (layers outside the scan keep caches of their own)
+            if shape in stacks or ("/while/body/" in line
+                                   and shape in slabs):
+                faults.append(line.strip()[:160])
+    assert writes, "no in-place write of the stacked cache"
+    assert not faults, faults
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    one_layer = sum(x.size // x.shape[0] * x.dtype.itemsize
+                    for x in jax.tree.leaves(state.stack))
+    assert temp < one_layer, (temp, one_layer)
+
+
+def test_smollm_decode_keeps_kv_cache_in_carry(one_chip, monkeypatch):
+    """SmolLM-135M's decode at 64 slots x 1024 float32 positions: each
+    layer's K and V (64 x 1024 x 3 x 64, stored with the position axis
+    minor-most) take the new rows in place and are read where they lie;
+    nothing copies a layer's slab out of the carry or back."""
+    import dataclasses
+
+    import repro.kernels.l2r_gemm.ops as ops
+    from repro.configs import get_config
+    from repro.core.quant import QuantConfig
+
+    # the dispatcher would pick the CPU's path: steer it to the chip's
+    monkeypatch.setattr(ops, "_resolve", lambda backend: "pallas-tpu")
+    cfg = dataclasses.replace(get_config("smollm-135m"), l2r=QuantConfig())
+    _decode_keeps_stack_in_place(cfg, one_chip, 64, 1024)
+
+
 def test_mla_decode_step_256_slots(one_chip, monkeypatch):
-    """One DeepSeek-V2-Lite MLA layer's decode step at 256 slots over a
-    1024-position float32 latent cache: the L2R projections on the
-    compiled kernels, the absorbed attention around them."""
+    """DeepSeek-V2-Lite's decode at 256 slots over a 1024-position
+    float32 latent cache.  One MLA layer: the L2R projections on the
+    compiled kernels, the absorbed attention around them.  The cell's
+    cut (5 layers, 8 held experts, 12,800 ids): the stacked latent
+    caches stay in the scan carry, as SmolLM's K and V do."""
     import dataclasses
 
     import repro.kernels.l2r_gemm.ops as ops
@@ -180,3 +274,6 @@ def test_mla_decode_step_256_slots(one_chip, monkeypatch):
     pos = jax.ShapeDtypeStruct((b, 1), jnp.int32, sharding=one_chip)
     text = jax.jit(step).lower(params, x, cache, pos).compile().as_text()
     assert "tpu_custom_call" in text
+    cut = dataclasses.replace(cfg, n_layers=5, n_experts_held=8,
+                              vocab=12_800)
+    _decode_keeps_stack_in_place(cut, one_chip, b, length)
